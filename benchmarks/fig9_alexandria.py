@@ -11,11 +11,12 @@ a hive-partitioned dataset (``part = spg % N_PARTS``), then
   every other partition before a single footer is opened (the pruning
   counters ride along in the derived fields), and
 - ``fig9/scan-sharded-w<k>/n=...``  a multi-process shard-per-worker scan:
-  partitions are placed onto worker processes with the mesh-placement
-  rules from :mod:`repro.distributed.sharding` when jax is importable
-  (``NamedSharding.devices_indices_map`` over a 1-D data mesh), falling
-  back to contiguous blocks on jax-free boxes; each worker opens the
-  dataset itself and reads only its partitions.
+  partitions are placed onto worker processes in contiguous blocks (what
+  a 1-D data mesh gives on one host); each worker opens the dataset
+  itself and reads only its partitions.  The parent never touches JAX
+  before it spawns, and under the ``jax`` decode backend the row is not
+  run: the device belongs to the parent, and the workers would have to
+  take it or decode on the host.
 
 ``scripts/check_perf.py`` gates ``fig9 partition-prune`` on the
 selective-vs-full ratio of this suite's artifact.
@@ -28,6 +29,7 @@ import os
 from typing import List
 
 from repro.core import ParquetDB
+from repro.core.backend import active_backend
 from repro.core.expressions import IsIn, field
 
 from .alexandria import write_json_shards
@@ -37,45 +39,11 @@ N_PARTS = 16  # hive partitions: part = spg % N_PARTS
 SELECTIVE_PART = 3
 
 
-def _placement(n_parts: int, n_workers: int) -> tuple:
-    """-> (assignment, mode): partition indices per worker.
-
-    Reuses the distributed mesh-placement rules when jax is available: a
-    1-D ``("pod", "data", "model")`` mesh over the host's devices, the
-    ``batch`` logical axis sharded across it, and the partition index
-    range split by ``NamedSharding.devices_indices_map`` — the same
-    placement a data-parallel loader would get.  Jax-free (or too few
-    devices): contiguous blocks, which is what the mesh degenerates to on
-    one host anyway.
-    """
-    try:
-        import jax
-        import numpy as np
-        from jax.sharding import Mesh, NamedSharding
-
-        from repro.distributed.sharding import spec_for
-
-        devs = jax.devices()
-        if len(devs) >= n_workers and n_parts % n_workers == 0:
-            mesh = Mesh(np.array(devs[:n_workers]).reshape(1, n_workers, 1),
-                        ("pod", "data", "model"))
-            spec = spec_for((n_parts,), ("batch",), mesh)
-            imap = NamedSharding(mesh, spec).devices_indices_map((n_parts,))
-            assign = []
-            seen = set()
-            for dev in devs[:n_workers]:
-                sl = imap[dev][0]
-                block = [i for i in range(*sl.indices(n_parts))
-                         if i not in seen]
-                seen.update(block)
-                assign.append(block)
-            if seen == set(range(n_parts)):
-                return assign, "mesh"
-    except Exception:
-        pass
+def _placement(n_parts: int, n_workers: int) -> List[List[int]]:
+    """Partition indices per worker, in contiguous blocks."""
     step = math.ceil(n_parts / n_workers)
     return [list(range(i, min(i + step, n_parts)))
-            for i in range(0, n_parts, step)], "blocks"
+            for i in range(0, n_parts, step)]
 
 
 def _scan_shard(args) -> int:
@@ -158,13 +126,12 @@ def run(scale: str = "small") -> List[dict]:
                        rows=n_total))
 
         n_workers = min(4, os.cpu_count() or 1)
-        if n_workers > 1:
-            assign, mode = _placement(N_PARTS, n_workers)
+        if n_workers > 1 and active_backend().name != "jax":
+            assign = _placement(N_PARTS, n_workers)
             holder = {}
             t_shard = timeit(lambda: holder.setdefault(
                 "n", _sharded_scan(ppath, n_workers, assign)))
             assert holder["n"] == n_total, (holder["n"], n_total)
             out.append(row(f"fig9/scan-sharded-w{n_workers}/n={n_total}",
-                           t_shard, rows=n_total, workers=n_workers,
-                           placement=mode))
+                           t_shard, rows=n_total, workers=n_workers))
     return out

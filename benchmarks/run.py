@@ -22,6 +22,8 @@ import platform
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 SUITES = ["fig5_create_read", "fig6_formats", "fig7_needle", "fig8_update",
           "fig9_alexandria", "fig10_ops", "fig11_aggregate", "fig12_serve",
           "pipeline_bench", "kernels_bench", "ckpt_bench"]
@@ -72,6 +74,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.quick:
         args.scale = "quick"
+    enable_compile_cache()
 
     only = args.only.split(",") if args.only else None
     all_rows = []

@@ -16,14 +16,15 @@ Selection:
 - :func:`set_backend` at runtime (tests, benchmarks), or
 - default: ``numpy``.
 
-The jax import probe is cached process-wide (:func:`jax_available`), so a
-``jax``-selected run on a machine without jax degrades to numpy after one
-cheap check — CI's perf-smoke job relies on this staying fast.
+Selecting ``jax`` where jax does not import raises :class:`RuntimeError`;
+no selection quietly decodes on the host instead.
 """
 from __future__ import annotations
 
 import os
-from typing import Dict, Optional
+import threading
+from collections import Counter
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -76,21 +77,38 @@ class JaxDecodeBackend(DecodeBackend):
     x64-disabled mode), so a page is routed only when every decoded value is
     exactly representable there — otherwise the numpy reference runs.  The
     gate keeps the backend *bit-identical* to numpy by construction.
+
+    The kernels run compiled on an accelerator and in Pallas interpret mode
+    on the CPU platform (``interpret``).  Every page the backend sees is
+    counted where it ran: ``device_pages`` and ``host_pages`` map an
+    encoding name — or ``"filter"`` for a :meth:`range_mask` call (one page)
+    and ``"minmax"`` for a :meth:`minmax` call (one decoded batch) — to a
+    count, so a run can show that its work reached the device.
     """
 
     name = "jax"
 
     def __init__(self):
+        import jax
+
         from repro.kernels import ops  # deferred: jax import is heavy
         self._ops = ops
-        self._interpret = ops.default_interpret()
+        self.interpret = jax.default_backend() == "cpu"
+        self.device_pages: Counter = Counter()
+        self.host_pages: Counter = Counter()
+        self._count_lock = threading.Lock()
 
-    # -- safety gates --------------------------------------------------------
+    def _count(self, on_device: bool, family: str, pages: int = 1) -> None:
+        with self._count_lock:
+            (self.device_pages if on_device else self.host_pages)[family] \
+                += pages
+
+    # -- safety gate ---------------------------------------------------------
     @staticmethod
     def _fits_i32(*vals) -> bool:
         return all(_INT32_MIN <= int(v) <= _INT32_MAX for v in vals)
 
-    def _routable(self, encoding: str, meta: dict, n: int,
+    def _routable(self, encoding: str, meta: dict, payload, n: int,
                   dt: np.dtype) -> bool:
         if n == 0:
             return False
@@ -101,7 +119,16 @@ class JaxDecodeBackend(DecodeBackend):
             return (dt.kind in "iu" and bits <= 31
                     and self._fits_i32(ref, ref + (1 << bits) - 1))
         if encoding == enc.DICT:
-            return meta["bits"] <= 31  # values checked against the dict below
+            if meta["bits"] > 31:
+                return False
+            # the gather runs in the dictionary dtype on device, so the
+            # dictionary's actual values must be 32-bit exact
+            uniq = np.frombuffer(payload[:meta["dict_len"]],
+                                 dt.newbyteorder("<"))
+            if dt.kind in "iu":
+                return not len(uniq) \
+                    or self._fits_i32(uniq.min(), uniq.max())
+            return dt == np.float32
         if encoding == enc.DELTA:
             bits, first = meta["bits"], meta["first"]
             if dt.kind not in "iu" or bits > 31:
@@ -116,85 +143,53 @@ class JaxDecodeBackend(DecodeBackend):
     def decode(self, encoding: str, meta: dict, payload, n: int,
                np_dtype, out: Optional[np.ndarray] = None) -> np.ndarray:
         dt = np.dtype(np_dtype)
-        if not self._routable(encoding, meta, n, dt):
+        if not self._routable(encoding, meta, payload, n, dt):
+            self._count(False, encoding)
             return enc.decode(encoding, meta, payload, n, np_dtype, out=out)
-        payload = bytes(payload)  # kernels take contiguous host bytes
-        if encoding == enc.DICT:
-            # gate on the dictionary's actual values: the gather runs in the
-            # dictionary dtype on device, which must be 32-bit exact
-            dl = meta["dict_len"]
-            uniq = np.frombuffer(payload[:dl],
-                                 np.dtype(dt).newbyteorder("<"))
-            if dt.kind in "iu":
-                if len(uniq) and not self._fits_i32(uniq.min(), uniq.max()):
-                    return enc.decode(encoding, meta, payload, n, np_dtype,
-                                      out=out)
-            elif dt != np.float32:
-                return enc.decode(encoding, meta, payload, n, np_dtype,
-                                  out=out)
-        # ask the device for int32 where the gate proved values fit: jax's
-        # x64-disabled mode would otherwise truncate int64 with a warning
-        dev_dt = (np.dtype(np.int32)
-                  if encoding in (enc.BITPACK, enc.DELTA) and dt.kind in "iu"
-                  else dt)
-        vals = self._ops.decode_on_device(encoding, meta, payload, n, dev_dt,
-                                          interpret=self._interpret)
-        vals = np.asarray(vals).astype(dt, copy=False)
+        # a single page is a batch of one
+        vals = self._ops.decode_batch_on_device(
+            encoding, [(encoding, meta, payload, n)], dt,
+            interpret=self.interpret)
+        self._count(True, encoding)
         if out is not None:
             out[:] = vals
             return out
         return vals
-
-    # encodings with a fused segmented device kernel (kernels/segmented.py)
-    _SEG_DEVICE = frozenset([enc.BITPACK, enc.DICT, enc.DELTA])
-
-    def _dict_exact(self, meta: dict, payload, dt: np.dtype) -> bool:
-        """Is this DICT page's dictionary 32-bit exact on device?"""
-        uniq = np.frombuffer(payload[:meta["dict_len"]],
-                             dt.newbyteorder("<"))
-        if dt.kind in "iu":
-            return not len(uniq) \
-                or self._fits_i32(uniq.min(), uniq.max())
-        return dt == np.float32
 
     def decode_batch(self, specs, np_dtype,
                      out: Optional[np.ndarray] = None) -> np.ndarray:
         """Morsel-fused decode: one device dispatch per encoding group.
 
         Routing is all-or-nothing *per encoding group*: a BITPACK / DICT /
-        DELTA group goes to the segmented kernels only when every page in it
-        passes the 32-bit gate (including the DICT dictionary-value check);
-        any other group — and any group with an unroutable page — decodes
-        through the numpy segmented reference, keeping the whole batch
-        byte-identical to the numpy backend.
+        DELTA / BSS group goes to the device only when every page in it
+        passes the 32-bit gate; any other group — and any group with an
+        unroutable page — decodes through the numpy segmented reference,
+        keeping the whole batch byte-identical to the numpy backend.
         """
         dt = np.dtype(np_dtype)
         starts = enc._spec_slices(specs)
-        total = int(starts[-1])
         if out is None:
-            out = np.empty(total, dt)
-        handled: set = set()
+            out = np.empty(int(starts[-1]), dt)
+        rest: List[int] = []
         for encoding, idxs in enc._batch_groups(specs).items():
-            if encoding not in self._SEG_DEVICE or len(idxs) < 2:
-                continue
             sub = [specs[i] for i in idxs]
-            if not all(self._routable(e, m, n, dt) for e, m, _, n in sub):
-                continue
-            if encoding == enc.DICT and not all(
-                    self._dict_exact(m, p, dt) for _, m, p, _ in sub):
+            if encoding not in self._ops.BATCHED or not all(
+                    self._routable(e, m, p, n, dt) for e, m, p, n in sub):
+                self._count(False, encoding, len(idxs))
+                rest.extend(idxs)
                 continue
             vals = self._ops.decode_batch_on_device(
-                encoding, sub, dt, interpret=self._interpret)
+                encoding, sub, dt, interpret=self.interpret)
+            self._count(True, encoding, len(idxs))
             pos = 0
             for i in idxs:
                 n = specs[i][3]
                 out[starts[i]:starts[i + 1]] = vals[pos:pos + n]
                 pos += n
-            handled.update(idxs)
-        if len(handled) < len(specs):
-            rest = [i for i in range(len(specs)) if i not in handled]
-            if not handled:
-                return enc.decode_batch(specs, dt, out=out)
+        if len(rest) == len(specs):
+            return enc.decode_batch(specs, dt, out=out)
+        if rest:
+            rest.sort()
             tmp = enc.decode_batch([specs[i] for i in rest], dt)
             pos = 0
             for i in rest:
@@ -220,12 +215,15 @@ class JaxDecodeBackend(DecodeBackend):
                 exact = self._fits_i32(values.min(), values.max())
         else:
             exact = False
+        exact = exact and len(values) > 0
+        self._count(exact, "filter")
         if not exact:
             return super().range_mask(values, lo, hi)
         import jax.numpy as jnp
-        mask, _ = self._ops.filter_range(jnp.asarray(values), lo, hi,
-                                         interpret=self._interpret)
-        return np.asarray(mask)
+        n = len(values)
+        mask, _ = self._ops.filter_range(jnp.asarray(_pow2_pad(values)),
+                                         lo, hi, interpret=self.interpret)
+        return np.asarray(mask)[:n]
 
     # min/max are pure comparisons — no arithmetic — so the only gate is
     # that jnp.asarray must not truncate the values: <=32-bit ints and
@@ -235,15 +233,28 @@ class JaxDecodeBackend(DecodeBackend):
 
     def minmax(self, values: np.ndarray):
         dt = values.dtype
-        if dt.kind + str(dt.itemsize) not in self._MINMAX_SAFE \
-                or len(values) == 0:
+        routable = (dt.kind + str(dt.itemsize) in self._MINMAX_SAFE
+                    and len(values) > 0)
+        self._count(routable, "minmax")
+        if not routable:
             return super().minmax(values)
         import jax.numpy as jnp
-        page = min(len(values), 4096)
-        mins, maxs = self._ops.page_minmax(jnp.asarray(values), page,
-                                           interpret=self._interpret)
+        values = _pow2_pad(values)
+        mins, maxs = self._ops.page_minmax(jnp.asarray(values),
+                                           min(len(values), 4096),
+                                           interpret=self.interpret)
         return (np.asarray(mins).min().item(),
                 np.asarray(maxs).max().item())
+
+
+def _pow2_pad(values: np.ndarray) -> np.ndarray:
+    """Pad a non-empty array to a power-of-two length with copies of its
+    last value, so kernels jit'd on shape compile once per size bucket;
+    repeats change no min/max, and callers drop the padded mask slots."""
+    n = len(values)
+    size = 1 << max(n - 1, 0).bit_length()
+    return values if size == n else np.pad(values, (0, size - n),
+                                           mode="edge")
 
 
 _jax_probe: Optional[bool] = None
@@ -295,10 +306,5 @@ def active_backend() -> DecodeBackend:
     """The backend the reader should decode through, honoring overrides.
 
     Precedence: :func:`set_backend` > ``REPRO_DECODE_BACKEND`` > numpy.
-    A jax selection on a jax-less machine silently degrades to numpy (the
-    probe is cached, so this costs one failed import per process).
     """
-    name = _active or os.environ.get(ENV_VAR, "numpy")
-    if name == "jax" and not jax_available():
-        name = "numpy"
-    return get_backend(name)
+    return get_backend(_active or os.environ.get(ENV_VAR, "numpy"))

@@ -56,7 +56,9 @@ residual filter, ``map_fn``) and the same order-preserving bounded merge,
 so output is byte-identical to the serial scan.  The default
 ``executor=None`` is AUTO: the footer's codec split picks threads for
 codec-compressed read sets and processes for GIL-bound ones big enough to
-amortize worker spawn (``PROCESS_MIN_ROWS``).
+amortize worker spawn (``PROCESS_MIN_ROWS``).  Workers always decode with
+the numpy backend; under the ``jax`` backend the device belongs to this
+process, so AUTO never picks processes and an explicit ``"process"`` raises.
 
 **Merge-on-read deltas.**  A manifest may carry a chain of delta files
 (:class:`repro.core.transactions.DeltaEntry`) — *upsert* files holding
@@ -95,6 +97,7 @@ from typing import (Any, Callable, Dict, Generator, Iterable, List, Optional,
 import numpy as np
 
 from . import shm
+from .backend import active_backend, set_backend
 from .expressions import Expr
 from .fileformat import TPQReader, page_codec_split
 from .integrity import CorruptFooterError, IntegrityError, with_read_retries
@@ -293,7 +296,9 @@ def process_scan_pool(num_workers: int) -> ProcessPoolExecutor:
             ctx = multiprocessing.get_context(
                 os.environ.get(ENV_MP_CONTEXT, "spawn"))
             _PPOOL = ProcessPoolExecutor(max_workers=num_workers,
-                                         mp_context=ctx)
+                                         mp_context=ctx,
+                                         initializer=set_backend,
+                                         initargs=("numpy",))
             _PPOOL_WORKERS = num_workers
     return _PPOOL
 
@@ -1135,7 +1140,17 @@ class ScanPlan:
         would convoy on threads) go to the *process* pool when the scan is
         big enough to amortize worker spawn (``PROCESS_MIN_ROWS``).  Either
         way the output stays byte-identical — only wall-clock changes.
+
+        Under the ``jax`` decode backend this process holds the device, and
+        a worker process could neither take it nor decode there, so AUTO
+        never picks ``"process"`` and an explicit ``"process"`` raises.
         """
+        on_device = active_backend().name == "jax"
+        if self._executor == "process" and on_device:
+            raise RuntimeError(
+                "executor='process' cannot run under the jax decode backend: "
+                "the device belongs to this process, and scan workers would "
+                "decode on the host; use executor='thread' or None")
         if self._num_threads <= 1 or len(morsels) <= 1:
             return "serial"
         if self._executor is not None:
@@ -1146,7 +1161,7 @@ class ScanPlan:
         for frag, rgs in morsels:
             rd = self._reader_of(frag.file)
             rows += sum(rd.row_group_num_rows(i) for i in rgs)
-        if rows >= PROCESS_MIN_ROWS:
+        if rows >= PROCESS_MIN_ROWS and not on_device:
             return "process"
         return "serial" if self._threads_auto else "thread"
 

@@ -132,14 +132,18 @@ class ShardedLoader:
             yield item
 
 
-def device_feed(tokens: np.ndarray, vocab: int, *, interpret: bool = True):
+def device_feed(tokens: np.ndarray, vocab: int):
     """Ship tokens to the device bitpacked; decode with the Pallas kernel.
 
     (B, S) int32 host tokens -> (B, S) int32 device tokens, having moved
-    ceil(log2 V)/32 of the bytes over PCIe.
+    ceil(log2 V)/32 of the bytes over PCIe.  The kernel runs in the jax
+    decode backend's mode: compiled on an accelerator, interpreted on CPU.
     """
     import jax.numpy as jnp
+
+    from ..core.backend import get_backend
     from ..kernels import bitunpack
+    interpret = get_backend("jax").interpret
     B, S = tokens.shape
     k = max(int(vocab - 1).bit_length(), 1)
     packed = enc.pack_bits(tokens.reshape(-1).astype(np.uint64), k)

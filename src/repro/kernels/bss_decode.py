@@ -25,7 +25,7 @@ def _bss_kernel(planes_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bss_decode(byte_planes: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def bss_decode(byte_planes: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
     """byte_planes: (4, n) uint8 -> (n,) float32."""
     assert byte_planes.shape[0] == 4, "float32 has 4 byte planes"
     n = byte_planes.shape[1]

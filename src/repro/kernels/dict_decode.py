@@ -33,7 +33,7 @@ def _dict_kernel(idx_ref, dict_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dict_decode(indices: jnp.ndarray, dictionary: jnp.ndarray, *,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool = False) -> jnp.ndarray:
     """out[i] = dictionary[indices[i]]."""
     n, d = indices.shape[0], dictionary.shape[0]
     if d > MAX_ONEHOT_DICT or n == 0:

@@ -4,9 +4,15 @@
 (:mod:`repro.core.encodings`) and the TPU: the host hands over the *encoded*
 payload (as uint8/uint32 arrays) and the matching footer metadata; decode runs
 as Pallas kernels next to the consumer.  This is the beyond-paper
-serialization-bottleneck fix for TPU (DESIGN.md §2, §7).
+serialization-bottleneck fix for TPU.
 
-``interpret`` defaults to True off-TPU so the whole path validates on CPU.
+BITPACK, DICT and DELTA decode through the segmented kernels
+(:mod:`.segmented`), float32 BSS through ``bss_decode`` over the pages' byte
+planes laid side by side — whether a morsel holds many pages or one: a
+single page is a batch of one, so both entry points share one kernel per
+encoding and one set of compiled shapes.  Kernels run compiled unless the
+caller passes ``interpret=True`` (the decode backend does so on the CPU
+platform).
 """
 from __future__ import annotations
 
@@ -28,72 +34,62 @@ from .stats_kernel import page_minmax
 
 __all__ = ["bitunpack", "bss_decode", "delta_decode", "dict_decode",
            "filter_range", "page_minmax", "decode_on_device",
-           "decode_batch_on_device", "default_interpret",
-           "plan_segments", "seg_bitunpack", "seg_dict_decode",
-           "seg_delta_decode"]
+           "decode_batch_on_device", "BATCHED", "plan_segments",
+           "seg_bitunpack", "seg_dict_decode", "seg_delta_decode"]
 
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _payload_words(payload: bytes) -> jnp.ndarray:
-    pad = (-len(payload)) % 4
-    if pad:
-        payload = payload + b"\x00" * pad
-    return jnp.asarray(np.frombuffer(payload, np.uint32))
+# encodings with a fused multi-page device decode
+BATCHED = frozenset([enc.BITPACK, enc.DICT, enc.DELTA, enc.BSS])
 
 
 def decode_on_device(encoding: str, meta: dict, payload: bytes, n: int,
-                     np_dtype, *, interpret: bool = True) -> jnp.ndarray:
-    """Device-side equivalent of ``encodings.decode`` for the kernelized
-    encodings (BITPACK / DICT / DELTA / BSS).  Others fall back to host decode
-    + transfer (PLAIN has nothing to decode anyway)."""
+                     np_dtype, *, interpret: bool = False) -> jnp.ndarray:
+    """Device-side equivalent of ``encodings.decode`` for one page of the
+    kernelized encodings (BITPACK / DICT / DELTA at <= 31 bits, float32
+    BSS).  Others fall back to host decode + transfer (PLAIN has nothing
+    to decode anyway).  Values come back in the 32-bit device dtype."""
     dt = np.dtype(np_dtype)
-    if encoding == enc.BITPACK:
-        vals = bitunpack(_payload_words(payload), n, meta["bits"],
-                         interpret=interpret)
-        if dt == np.bool_:
-            return vals.astype(jnp.bool_)
-        return (vals + jnp.int32(meta["ref"])).astype(dt) \
-            if meta["ref"] else vals.astype(dt)
-    if encoding == enc.DICT:
-        dl = meta["dict_len"]
-        dictionary = jnp.asarray(
-            np.frombuffer(payload[:dl], np.dtype(dt).newbyteorder("<")).astype(dt))
-        idx = bitunpack(_payload_words(payload[dl:]), n, meta["bits"],
-                        interpret=interpret)
-        return dict_decode(idx, dictionary, interpret=interpret)
-    if encoding == enc.DELTA:
-        # encoder stores n-1 deltas; prepend a zero slot for the kernel
-        zz = enc.unpack_bits(payload, n - 1, meta["bits"]) if n > 1 else \
-            np.zeros(0, np.uint64)
-        zz = jnp.asarray(np.concatenate([[0], zz]).astype(np.uint32))
-        return delta_decode(zz, jnp.int32(meta["first"]),
-                            interpret=interpret).astype(dt)
-    if encoding == enc.BSS and dt == np.float32:
-        planes = jnp.asarray(
-            np.frombuffer(payload, np.uint8).reshape(dt.itemsize, n))
-        return bss_decode(planes, interpret=interpret)
+    if encoding in BATCHED and (encoding != enc.BSS or dt == np.float32):
+        vals = _batched(encoding, [(encoding, meta, payload, n)], dt,
+                        interpret)
+        return vals[:n].astype(jax.dtypes.canonicalize_dtype(dt))
     # fallback: host decode, then transfer
     return jnp.asarray(enc.decode(encoding, meta, payload, n, dt))
 
 
 def decode_batch_on_device(encoding: str, specs, np_dtype, *,
-                           interpret: bool = True) -> np.ndarray:
+                           interpret: bool = False) -> np.ndarray:
     """ONE fused device dispatch decoding a whole morsel's pages of a single
     encoding group.
 
-    ``specs`` is ``[(encoding, meta, payload, n), ...]`` with at least two
-    non-empty pages, all the given ``encoding``; the caller
+    ``specs`` is ``[(encoding, meta, payload, n), ...]`` with at least one
+    non-empty page, all the given ``encoding``; the caller
     (:meth:`JaxDecodeBackend.decode_batch`) has already proven every page
     32-bit exact.  Returns the concatenated value stream as a host array of
     ``np_dtype`` — byte-identical to per-page decode by construction.
     """
     dt = np.dtype(np_dtype)
+    total = sum(n for _, _, _, n in specs)
+    vals = np.asarray(_batched(encoding, specs, dt, interpret))
+    return vals[:total].astype(dt, copy=False)
+
+
+def _batched(encoding: str, specs, dt: np.dtype,
+             interpret: bool) -> jnp.ndarray:
+    """Stage ``specs`` and run the device decode of ``encoding``; the
+    device result is padded past the value count to a power of two."""
     ns = np.array([n for _, _, _, n in specs], np.int64)
-    ks = np.array([m["bits"] for _, m, _, _ in specs], np.int64)
     total = int(ns.sum())
+    if encoding == enc.BSS:
+        # plane b of the morsel is plane b of every page, side by side
+        planes = np.zeros((4, 1 << max(total - 1, 0).bit_length()),
+                          np.uint8)
+        pos = 0
+        for _, _, p, n in specs:
+            planes[:, pos:pos + n] = np.frombuffer(
+                p, np.uint8, count=4 * n).reshape(4, n)
+            pos += n
+        return bss_decode(planes, interpret=interpret)
+    ks = np.array([m["bits"] for _, m, _, _ in specs], np.int64)
     if encoding == enc.BITPACK:
         words, w0, sh, mask = plan_segments([p for _, _, p, _ in specs],
                                             ns, ks)
@@ -101,9 +97,7 @@ def decode_batch_on_device(encoding: str, specs, np_dtype, *,
         if dt != np.bool_:
             refs[:total] = np.repeat(
                 np.array([m["ref"] for _, m, _, _ in specs], np.int64), ns)
-        vals = np.asarray(seg_bitunpack(words, w0, sh, mask, refs,
-                                        interpret=interpret))
-        return vals[:total].astype(dt, copy=False)
+        return seg_bitunpack(words, w0, sh, mask, refs, interpret=interpret)
     if encoding == enc.DICT:
         le = dt.newbyteorder("<")
         dicts = [np.frombuffer(p[:m["dict_len"]], le)
@@ -118,9 +112,8 @@ def decode_batch_on_device(encoding: str, specs, np_dtype, *,
         # the dictionary VALUES fit, so the host-side narrow is lossless
         dcat = np.concatenate(dicts).astype(
             np.int32 if dt.kind in "iu" else dt)
-        vals = np.asarray(seg_dict_decode(words, w0, sh, mask, dcat, doff,
-                                          interpret=interpret))
-        return vals[:total].astype(dt, copy=False)
+        return seg_dict_decode(words, w0, sh, mask, dcat, doff,
+                               interpret=interpret)
     if encoding == enc.DELTA:
         # each page packs n-1 zigzag'd deltas; page-start slots are zero in
         # the scatter so one global cumsum recovers every page (wrap-exact)
@@ -139,15 +132,14 @@ def decode_batch_on_device(encoding: str, specs, np_dtype, *,
         dpos = np.zeros(w0.shape[0], np.int32)
         dpos[:d_total] = np.nonzero(dmask)[0]
         firsts = np.array([m["first"] for _, m, _, _ in specs], np.int32)
-        vals = np.asarray(seg_delta_decode(
+        return seg_delta_decode(
             words, w0, sh, mask, dpos, starts.astype(np.int32), pid, firsts,
-            np.array([d_total], np.int32), interpret=interpret))
-        return vals[:total].astype(dt, copy=False)
-    raise ValueError(f"no segmented kernel for encoding {encoding!r}")
+            np.array([d_total], np.int32), interpret=interpret)
+    raise ValueError(f"no device decode for encoding {encoding!r}")
 
 
 def decode_and_filter(encoding: str, meta: dict, payload: bytes, n: int,
-                      np_dtype, lo, hi, *, interpret: bool = True
+                      np_dtype, lo, hi, *, interpret: bool = False
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused decode -> range predicate; returns (values, mask, block_counts)."""
     vals = decode_on_device(encoding, meta, payload, n, np_dtype,

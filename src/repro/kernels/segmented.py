@@ -20,7 +20,8 @@ fused dispatch:
 
 All functions take pre-staged host arrays from :func:`plan_segments` and are
 jit'd on shape: inputs are padded to power-of-two lengths so repeated morsel
-shapes hit the trace cache.  ``interpret`` defaults True off-TPU.
+shapes hit the trace cache.  Kernels run compiled unless the caller asks
+for ``interpret=True`` (the CPU backend does).
 """
 from __future__ import annotations
 
@@ -96,20 +97,23 @@ def _combine_kernel(lo_ref, hi_ref, mask_ref, ref_ref, out_ref):
 
 def _combine(lo, hi, mask, refs, interpret: bool) -> jnp.ndarray:
     n = lo.shape[0]  # static under jit; already power-of-two padded
-    blocks = -(-n // LANE_VALUES)
-    spec = pl.BlockSpec((LANE_VALUES,), lambda i: (i,))
+    # a morsel shorter than one lane block is one whole-array block: the
+    # TPU admits a rank-1 block only as a multiple of its tiling or as
+    # the full array, and a power of two below 1024 divides neither way
+    block = min(n, LANE_VALUES)
+    spec = pl.BlockSpec((block,), lambda i: (i,))
     return pl.pallas_call(
         _combine_kernel,
-        grid=(blocks,),
+        grid=(n // block,),
         in_specs=[spec, spec, spec, spec],
         out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((blocks * LANE_VALUES,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
         interpret=interpret,
-    )(lo, hi, mask, refs)[:n]
+    )(lo, hi, mask, refs)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _seg_values(words, w0, sh, mask, refs, *, interpret: bool = True):
+def _seg_values(words, w0, sh, mask, refs, *, interpret: bool = False):
     """Gather + combine: the packed-value stream of a whole morsel."""
     w = words.astype(jnp.uint32)
     lo = w[w0] >> sh
@@ -119,7 +123,7 @@ def _seg_values(words, w0, sh, mask, refs, *, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def seg_bitunpack(words, w0, sh, mask, refs, *, interpret: bool = True
+def seg_bitunpack(words, w0, sh, mask, refs, *, interpret: bool = False
                   ) -> jnp.ndarray:
     """BITPACK a whole morsel: unpack + frame-of-reference add, one dispatch.
 
@@ -130,7 +134,7 @@ def seg_bitunpack(words, w0, sh, mask, refs, *, interpret: bool = True
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def seg_dict_decode(words, w0, sh, mask, dictionary, doff, *,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """DICT a whole morsel: one index unpack + one gather of the
     concatenated per-page dictionaries (``doff`` = per-element dict base)."""
     idx = _seg_values(words, w0, sh, mask, jnp.zeros_like(w0),
@@ -140,7 +144,7 @@ def seg_dict_decode(words, w0, sh, mask, dictionary, doff, *,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def seg_delta_decode(words, w0, sh, mask, dpos, starts, pid, firsts, n, *,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     """DELTA a whole morsel: one zigzag unpack + ONE global cumsum.
 
     ``dpos`` scatters each decoded delta to its output slot (page-start

@@ -7,6 +7,13 @@ XLA_FLAGS dance happens only inside ``dryrun.py``.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(axes) -> tuple:
+    # jax.make_mesh defaults to explicit-sharding axes; the model code
+    # shards through NamedSharding constraints, which need Auto axes
+    return (AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,12 +29,14 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {need} devices, have {len(devs)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             f"(dryrun.py sets this automatically)")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=_auto(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests use e.g. (2, 4) on 8 host devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=_auto(axes))
 
 
 # v5e-class hardware constants used by the roofline analysis

@@ -10,6 +10,7 @@ import time
 import jax
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs import registry
 from ..models import Model
 from ..serve.engine import ServeEngine
@@ -24,6 +25,7 @@ def main(argv=None):
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = registry.get_reduced(args.arch) if args.reduced \
         else registry.get(args.arch)

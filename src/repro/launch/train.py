@@ -13,6 +13,7 @@ import os
 import jax
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs import registry
 from ..data.sharded_loader import ShardedLoader
 from ..data.tokenstore import TokenStore
@@ -45,6 +46,7 @@ def main(argv=None):
     ap.add_argument("--workdir", default="/tmp/repro_train")
     ap.add_argument("--data", default=None, help="TokenStore path")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = registry.get_reduced(args.arch) if args.reduced \
         else registry.get(args.arch)

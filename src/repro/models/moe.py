@@ -25,13 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-# jax >= 0.6 promotes shard_map to the top-level namespace; 0.4.x keeps it
-# under jax.experimental — resolve once so both versions run the same path
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .config import ModelConfig
 from .layers import P, mlp_spec, swiglu
 
@@ -156,7 +149,7 @@ def _moe_shard_map(p: Dict, x: jnp.ndarray, cfg: ModelConfig, mesh):
 
     batch_part = batch_axes if len(batch_axes) > 1 else batch_axes[0]
     expert_spec = PS("model", None, None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(PS(batch_part, None, None), PS(None, None),
                   expert_spec, expert_spec, expert_spec),

@@ -48,6 +48,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Optional, Tuple
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import LoadConfig, MorselBudget, ParquetDB
 from repro.core.query import Query
 from repro.core.transactions import register_commit_listener
@@ -387,6 +388,7 @@ def main(argv=None) -> int:
     ap.add_argument("--morsel-budget", type=int, default=None)
     ap.add_argument("--num-threads", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     db = ParquetDB(args.path, args.name)
     server = DBServer(db, args.host, args.port,
                       max_concurrent=args.max_concurrent,

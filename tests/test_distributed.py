@@ -14,14 +14,9 @@ from jax.sharding import PartitionSpec as PS
 
 class TestSpecFor:
     def _mesh(self, shape=(2, 4), axes=("data", "model")):
-        # host platform has 1 device in this process: build an abstract mesh.
-        # jax >= 0.5 takes (axis_sizes, axis_names); 0.4.x wants one
-        # ((name, size), ...) shape tuple — probe the new form first.
+        # host platform has 1 device in this process: build an abstract mesh
         from jax.sharding import AbstractMesh
-        try:
-            return AbstractMesh(shape, axes)
-        except TypeError:
-            return AbstractMesh(tuple(zip(axes, shape)))
+        return AbstractMesh(shape, axes)
 
     def test_dense_weight(self):
         from repro.distributed.sharding import spec_for

@@ -33,14 +33,15 @@ class TestBitunpack:
         hi = 2**k if k < 32 else 2**31
         vals = RNG.integers(0, hi, n).astype(np.uint64)
         words = _packed_words(vals, k)
-        out = bitunpack(words, n, k)
+        out = bitunpack(words, n, k, interpret=True)
         oracle = ref.bitunpack(words, n, k)
         np.testing.assert_array_equal(
             np.asarray(out).astype(np.uint32), vals.astype(np.uint32))
         np.testing.assert_array_equal(np.asarray(out), np.asarray(oracle))
 
     def test_k0(self):
-        assert bitunpack(jnp.zeros(0, jnp.uint32), 5, 0).tolist() == [0] * 5
+        out = bitunpack(jnp.zeros(0, jnp.uint32), 5, 0, interpret=True)
+        assert out.tolist() == [0] * 5
 
 
 class TestDictDecode:
@@ -49,7 +50,8 @@ class TestDictDecode:
     def test_sweep(self, d, dtype):
         dictionary = (RNG.standard_normal(d) * 100).astype(dtype)
         idx = RNG.integers(0, d, 777).astype(np.int32)
-        out = dict_decode(jnp.asarray(idx), jnp.asarray(dictionary))
+        out = dict_decode(jnp.asarray(idx), jnp.asarray(dictionary),
+                          interpret=True)
         oracle = ref.dict_decode(jnp.asarray(idx), jnp.asarray(dictionary))
         np.testing.assert_allclose(np.asarray(out), dictionary[idx], rtol=1e-6)
         np.testing.assert_allclose(np.asarray(out), np.asarray(oracle), rtol=1e-6)
@@ -57,7 +59,8 @@ class TestDictDecode:
     def test_large_dict_falls_back_to_gather(self):
         dictionary = np.arange(10_000, dtype=np.int32)
         idx = RNG.integers(0, 10_000, 100).astype(np.int32)
-        out = dict_decode(jnp.asarray(idx), jnp.asarray(dictionary))
+        out = dict_decode(jnp.asarray(idx), jnp.asarray(dictionary),
+                          interpret=True)
         np.testing.assert_array_equal(np.asarray(out), dictionary[idx])
 
 
@@ -67,7 +70,8 @@ class TestDeltaDecode:
         arr = np.cumsum(RNG.integers(-100, 101, n)).astype(np.int64)
         arr = np.clip(arr, -2**30, 2**30)  # int32 range on device
         chosen, meta, payload = enc.encode(arr, "delta")
-        out = ops.decode_on_device(chosen, meta, payload, n, np.int32)
+        out = ops.decode_on_device(chosen, meta, payload, n, np.int32,
+                                   interpret=True)
         np.testing.assert_array_equal(np.asarray(out), arr.astype(np.int32))
 
     def test_carry_across_blocks(self):
@@ -75,14 +79,15 @@ class TestDeltaDecode:
         n = 4096 + 7
         arr = np.arange(n, dtype=np.int64) * 3 + 11
         chosen, meta, payload = enc.encode(arr, "delta")
-        out = ops.decode_on_device(chosen, meta, payload, n, np.int32)
+        out = ops.decode_on_device(chosen, meta, payload, n, np.int32,
+                                   interpret=True)
         np.testing.assert_array_equal(np.asarray(out), arr.astype(np.int32))
 
     def test_vs_oracle(self):
         zz = jnp.asarray(RNG.integers(0, 50, 3000).astype(np.uint32))
         first = jnp.int32(-17)
         np.testing.assert_array_equal(
-            np.asarray(delta_decode(zz, first)),
+            np.asarray(delta_decode(zz, first, interpret=True)),
             np.asarray(ref.delta_decode(zz, first)))
 
 
@@ -92,7 +97,7 @@ class TestBssDecode:
         arr = RNG.standard_normal(n).astype(np.float32)
         _, meta, payload = enc.encode(arr, "bss")
         planes = jnp.asarray(np.frombuffer(payload, np.uint8).reshape(4, n))
-        out = bss_decode(planes)
+        out = bss_decode(planes, interpret=True)
         oracle = ref.bss_decode(planes)
         np.testing.assert_array_equal(np.asarray(out), arr)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(oracle))
@@ -101,7 +106,8 @@ class TestBssDecode:
         arr = np.array([0.0, -0.0, np.inf, -np.inf, 1e-38, 3.4e38], np.float32)
         _, meta, payload = enc.encode(arr, "bss")
         planes = jnp.asarray(np.frombuffer(payload, np.uint8).reshape(4, len(arr)))
-        np.testing.assert_array_equal(np.asarray(bss_decode(planes)), arr)
+        out = bss_decode(planes, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), arr)
 
 
 class TestFilterKernel:
@@ -109,14 +115,15 @@ class TestFilterKernel:
     @pytest.mark.parametrize("n", [5, 2048, 6000])
     def test_sweep(self, dtype, n):
         x = (RNG.standard_normal(n) * 100).astype(dtype)
-        mask, counts = filter_range(jnp.asarray(x), -50, 50)
+        mask, counts = filter_range(jnp.asarray(x), -50, 50,
+                                    interpret=True)
         oracle = np.asarray(ref.filter_range(jnp.asarray(x), dtype(-50), dtype(50)))
         np.testing.assert_array_equal(np.asarray(mask), oracle)
         assert int(counts.sum()) == int(oracle.sum())
 
     def test_empty_range(self):
         x = jnp.arange(100, dtype=jnp.int32)
-        mask, counts = filter_range(x, 1000, 2000)
+        mask, counts = filter_range(x, 1000, 2000, interpret=True)
         assert int(counts.sum()) == 0 and not bool(mask.any())
 
 
@@ -126,7 +133,7 @@ class TestStatsKernel:
     def test_sweep(self, page, dtype):
         n = page * 7 + 13
         x = (RNG.standard_normal(n) * 1000).astype(dtype)
-        mins, maxs = page_minmax(jnp.asarray(x), page)
+        mins, maxs = page_minmax(jnp.asarray(x), page, interpret=True)
         # compare on the full pages; ragged tail is padded with x[-1]
         xr = np.concatenate([x, np.full(page * 8 - n, x[-1], dtype)]).reshape(8, page)
         np.testing.assert_array_equal(np.asarray(mins), xr.min(1))
@@ -134,7 +141,7 @@ class TestStatsKernel:
 
     def test_vs_oracle_exact_pages(self):
         x = jnp.asarray(RNG.standard_normal(4096).astype(np.float32))
-        mins, maxs = page_minmax(x, 512)
+        mins, maxs = page_minmax(x, 512, interpret=True)
         omin, omax = ref.page_minmax(x, 512)
         np.testing.assert_array_equal(np.asarray(mins), np.asarray(omin))
         np.testing.assert_array_equal(np.asarray(maxs), np.asarray(omax))
@@ -144,7 +151,7 @@ class TestStatsKernel:
 @settings(max_examples=30, deadline=None)
 def test_property_bitunpack_any_k_n(k, n):
     vals = RNG.integers(0, 2**k, n).astype(np.uint64)
-    out = bitunpack(_packed_words(vals, k), n, k)
+    out = bitunpack(_packed_words(vals, k), n, k, interpret=True)
     np.testing.assert_array_equal(np.asarray(out).astype(np.uint64), vals)
 
 
@@ -154,7 +161,8 @@ def test_property_delta_device_matches_host(xs):
     arr = np.array(xs, np.int64)
     chosen, meta, payload = enc.encode(arr, "delta")
     host = enc.decode(chosen, meta, payload, len(arr), np.int64)
-    dev = ops.decode_on_device(chosen, meta, payload, len(arr), np.int32)
+    dev = ops.decode_on_device(chosen, meta, payload, len(arr), np.int32,
+                               interpret=True)
     np.testing.assert_array_equal(np.asarray(dev), host.astype(np.int32))
 
 
@@ -169,5 +177,6 @@ def test_end_to_end_page_decode_matches_host():
         host = enc.decode(chosen, meta, payload, len(arr), arr.dtype)
         dt = np.float32 if encoding == "bss" else (
             np.int64 if encoding == "dict" else np.int32)
-        dev = np.asarray(ops.decode_on_device(chosen, meta, payload, len(arr), dt))
+        dev = np.asarray(ops.decode_on_device(
+            chosen, meta, payload, len(arr), dt, interpret=True))
         np.testing.assert_array_equal(dev.astype(arr.dtype), host)
