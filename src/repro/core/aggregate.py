@@ -49,6 +49,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from ..spans import span
 from .backend import active_backend
 from .dtypes import KIND_NUMERIC, KIND_STRING
 from .expressions import Expr
@@ -273,27 +274,29 @@ class AggregatePlan:
         remaining partial groups run through one restricted
         :class:`ScanPlan` (morsel-parallel, delta-exact).
         """
-        frags = self._plan.fragments()
-        ov = self._plan._overlay()
-        c = dataclasses.replace(self._plan._plan_counters)
-        accs: Dict[str, _ColAcc] = {col: _ColAcc() for col in self._spec}
-        restrict: Dict[str, List[int]] = {}
-        read_names = self._plan._read_schema.names
-        for frag in frags:
-            if frag.partition_pruned:
-                # the filter provably excludes this whole partition:
-                # contributes nothing, and the footer stays unopened
-                continue
-            rd = self._reader_of(frag.file)
-            cols_here = [n for n in read_names if n in rd.schema]
-            for i in frag.row_groups:
-                if self._covered(frag, rd, i, ov):
-                    self._acc_stats(accs, rd, i)
-                    c.groups_answered_by_stats += 1
-                    c.bytes_skipped_agg += rd.read_row_group_bytes(i,
-                                                                   cols_here)
-                else:
-                    restrict.setdefault(frag.file, []).append(i)
+        with span("query.plan"):  # footer statistics answer what they can
+            frags = self._plan.fragments()
+            ov = self._plan._overlay()
+            c = dataclasses.replace(self._plan._plan_counters)
+            accs: Dict[str, _ColAcc] = {col: _ColAcc()
+                                         for col in self._spec}
+            restrict: Dict[str, List[int]] = {}
+            read_names = self._plan._read_schema.names
+            for frag in frags:
+                if frag.partition_pruned:
+                    # the filter provably excludes this whole partition:
+                    # contributes nothing, and the footer stays unopened
+                    continue
+                rd = self._reader_of(frag.file)
+                cols_here = [n for n in read_names if n in rd.schema]
+                for i in frag.row_groups:
+                    if self._covered(frag, rd, i, ov):
+                        self._acc_stats(accs, rd, i)
+                        c.groups_answered_by_stats += 1
+                        c.bytes_skipped_agg += rd.read_row_group_bytes(
+                            i, cols_here)
+                    else:
+                        restrict.setdefault(frag.file, []).append(i)
         if restrict:
             part = ScanPlan([f for f in self._files if f in restrict],
                             self._reader_of, self._schema,
@@ -302,10 +305,12 @@ class AggregatePlan:
                             deltas=self._deltas, overlay=ov,
                             restrict=restrict)
             for t in part.execute(counters=c):
-                self._acc_table(accs, t)
+                with span("query.compute"):
+                    self._acc_table(accs, t)
         self._counters = c
         self._executed = True
-        return self._results(accs)
+        with span("query.compute"):
+            return self._results(accs)
 
     def _results(self, accs: Dict[str, _ColAcc]) -> Dict[str, Dict[str, Any]]:
         out: Dict[str, Dict[str, Any]] = {}

@@ -219,11 +219,8 @@ class JaxDecodeBackend(DecodeBackend):
         self._count(exact, "filter")
         if not exact:
             return super().range_mask(values, lo, hi)
-        import jax.numpy as jnp
-        n = len(values)
-        mask, _ = self._ops.filter_range(jnp.asarray(_pow2_pad(values)),
-                                         lo, hi, interpret=self.interpret)
-        return np.asarray(mask)[:n]
+        return self._ops.range_mask_on_device(values, lo, hi,
+                                              interpret=self.interpret)
 
     # min/max are pure comparisons — no arithmetic — so the only gate is
     # that jnp.asarray must not truncate the values: <=32-bit ints and
@@ -238,23 +235,7 @@ class JaxDecodeBackend(DecodeBackend):
         self._count(routable, "minmax")
         if not routable:
             return super().minmax(values)
-        import jax.numpy as jnp
-        values = _pow2_pad(values)
-        mins, maxs = self._ops.page_minmax(jnp.asarray(values),
-                                           min(len(values), 4096),
-                                           interpret=self.interpret)
-        return (np.asarray(mins).min().item(),
-                np.asarray(maxs).max().item())
-
-
-def _pow2_pad(values: np.ndarray) -> np.ndarray:
-    """Pad a non-empty array to a power-of-two length with copies of its
-    last value, so kernels jit'd on shape compile once per size bucket;
-    repeats change no min/max, and callers drop the padded mask slots."""
-    n = len(values)
-    size = 1 << max(n - 1, 0).bit_length()
-    return values if size == n else np.pad(values, (0, size - n),
-                                           mode="edge")
+        return self._ops.minmax_on_device(values, interpret=self.interpret)
 
 
 _jax_probe: Optional[bool] = None

@@ -43,6 +43,7 @@ import numpy as np
 
 from . import encodings as enc
 from . import integrity
+from ..spans import span
 from .backend import active_backend
 from .integrity import (CorruptFooterError, CorruptPageError,
                         TruncatedFileError)
@@ -675,59 +676,69 @@ class TPQReader:
                 # phase 2 materializes only the selected rows of the payload
                 # columns (late materialization — the page-slice and take
                 # are fused inside _read_column_page).
-                fschema = self.schema.select(filter_cols)
-                # single-column contiguous ranges evaluate through the
-                # decode backend's fused range_mask (Pallas filter_range
-                # on the jax backend); anything else through Expr.evaluate
-                rng = (filter_expr.as_range()
-                       if len(filter_cols) == 1 else None)
-                if rng is not None and rng[0] != filter_cols[0]:
-                    rng = None
-                kept: List[int] = []
-                sels: List[Optional[np.ndarray]] = []
-                fcache: Dict[int, Dict[str, Column]] = {}
-                for j in page_sel:
-                    fcols = {n: read_pages(n, [j]) for n in filter_cols}
-                    mask = None
-                    if rng is not None:
-                        fc = fcols[filter_cols[0]]
-                        if fc.dtype.kind == KIND_NUMERIC \
-                                and fc.validity is None:
-                            bounds = _inclusive_bounds(rng, fc.values.dtype)
-                            if bounds is not None:
-                                mask = np.asarray(active_backend().range_mask(
-                                    fc.values, bounds[0], bounds[1]), bool)
-                    if mask is None:
-                        mask = filter_expr.evaluate(Table(fschema, fcols))
-                    if mask.any():
-                        kept.append(j)
-                        sels.append(None if mask.all()
-                                    else np.nonzero(mask)[0])
-                        fcache[j] = fcols
+                with span("reader.filter"):
+                    fschema = self.schema.select(filter_cols)
+                    # single-column contiguous ranges evaluate through the
+                    # decode backend's fused range_mask (Pallas filter_range
+                    # on the jax backend); else through Expr.evaluate
+                    rng = (filter_expr.as_range()
+                           if len(filter_cols) == 1 else None)
+                    if rng is not None and rng[0] != filter_cols[0]:
+                        rng = None
+                    kept: List[int] = []
+                    sels: List[Optional[np.ndarray]] = []
+                    fcache: Dict[int, Dict[str, Column]] = {}
+                    for j in page_sel:
+                        fcols = {n: read_pages(n, [j])
+                                 for n in filter_cols}
+                        mask = None
+                        if rng is not None:
+                            fc = fcols[filter_cols[0]]
+                            if fc.dtype.kind == KIND_NUMERIC \
+                                    and fc.validity is None:
+                                bounds = _inclusive_bounds(rng,
+                                                           fc.values.dtype)
+                                if bounds is not None:
+                                    mask = np.asarray(
+                                        active_backend().range_mask(
+                                            fc.values, bounds[0],
+                                            bounds[1]), bool)
+                        if mask is None:
+                            mask = filter_expr.evaluate(
+                                Table(fschema, fcols))
+                        if mask.any():
+                            kept.append(j)
+                            sels.append(None if mask.all()
+                                        else np.nonzero(mask)[0])
+                            fcache[j] = fcols
                 if not kept:
                     continue
-                if counters is not None:
-                    counters.rows_skipped_late += sum(
-                        len(fcache[j][filter_cols[0]]) - len(s)
-                        for j, s in zip(kept, sels) if s is not None)
-                cols: Dict[str, Column] = {}
-                for name in names:
-                    if name in filter_cols:
-                        pieces = [fcache[j][name] if s is None
-                                  else fcache[j][name].take(s)
-                                  for j, s in zip(kept, sels)]
-                        cols[name] = (pieces[0] if len(pieces) == 1
-                                      else concat_columns(pieces))
-                    else:
-                        cols[name] = read_pages(name, kept, sels)
-                t = Table(sub_schema, cols)
+                with span("reader.payload"):
+                    if counters is not None:
+                        counters.rows_skipped_late += sum(
+                            len(fcache[j][filter_cols[0]]) - len(s)
+                            for j, s in zip(kept, sels) if s is not None)
+                    cols: Dict[str, Column] = {}
+                    for name in names:
+                        if name in filter_cols:
+                            pieces = [fcache[j][name] if s is None
+                                      else fcache[j][name].take(s)
+                                      for j, s in zip(kept, sels)]
+                            cols[name] = (pieces[0] if len(pieces) == 1
+                                          else concat_columns(pieces))
+                        else:
+                            cols[name] = read_pages(name, kept, sels)
+                    t = Table(sub_schema, cols)
             else:
-                cols = {name: read_pages(name, page_sel) for name in names}
-                t = Table(sub_schema, cols)
+                with span("reader.payload"):
+                    cols = {name: read_pages(name, page_sel)
+                            for name in names}
+                    t = Table(sub_schema, cols)
                 if filter_expr is not None:
-                    mask = filter_expr.evaluate(t)
-                    if not mask.all():
-                        t = t.filter_mask(mask)
+                    with span("reader.filter"):
+                        mask = filter_expr.evaluate(t)
+                        if not mask.all():
+                            t = t.filter_mask(mask)
             if t.num_rows:
                 yield t
 

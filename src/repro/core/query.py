@@ -62,6 +62,7 @@ from typing import Any, Dict, Generator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from . import nested
+from ..spans import query_span, rooted, span
 from .aggregate import AggregatePlan, _normalize_spec
 from .dtypes import DType, KIND_NULL, KIND_NUMERIC, KIND_STRING
 from .expressions import (And, Arith, Comparison, Expr, FieldRef, IsIn,
@@ -809,6 +810,10 @@ class Query:
 
     # -------------------------------------------------------------- compile
     def _compile(self) -> _Compiled:
+        with span("query.plan"):
+            return self._compile_plan()
+
+    def _compile_plan(self) -> _Compiled:
         man, schema = self._snapshot()
         out_pre = ([] if self._aggregated()
                    else self._output_names(schema))
@@ -876,11 +881,12 @@ class Query:
         seen: Optional[set] = set() if self._distinct else None
         try:
             for t in gen:
-                for name, ve in cp.computed:
-                    t = t.set_column(name, ve.evaluate_column(t))
-                t = t.select(cp.out_pre)
-                if seen is not None:
-                    t = _distinct_batch(t, seen)
+                with span("query.compute"):
+                    for name, ve in cp.computed:
+                        t = t.set_column(name, ve.evaluate_column(t))
+                    t = t.select(cp.out_pre)
+                    if seen is not None:
+                        t = _distinct_batch(t, seen)
                 yield t
         finally:
             gen.close()
@@ -908,21 +914,25 @@ class Query:
             if cap is None:
                 # full sort: collect once, concat once (no per-batch copy)
                 parts = list(stream)
-                acc = concat_tables(parts) if parts else self._empty_out(cp)
+                with span("query.compute"):
+                    acc = (concat_tables(parts) if parts
+                           else self._empty_out(cp))
             else:
                 # top-k: fold each batch into a pruned accumulator
                 acc = None
                 for t in stream:
-                    acc = t if acc is None else concat_tables([acc, t])
-                    if acc.num_rows > cap:
-                        idx = _sort_indices(acc, self._order)[:cap]
-                        acc = acc.take(np.sort(idx))  # keep arrival order
+                    with span("query.compute"):
+                        acc = t if acc is None else concat_tables([acc, t])
+                        if acc.num_rows > cap:
+                            idx = _sort_indices(acc, self._order)[:cap]
+                            acc = acc.take(np.sort(idx))  # arrival order
                 if acc is None:
                     acc = self._empty_out(cp)
-            acc = acc.take(_sort_indices(acc, self._order))
-            if opstats is not None:
-                opstats["rows_sorted"] = acc.num_rows
-            out = self._slice_limit(acc)
+            with span("query.compute"):
+                acc = acc.take(_sort_indices(acc, self._order))
+                if opstats is not None:
+                    opstats["rows_sorted"] = acc.num_rows
+                out = self._slice_limit(acc)
         else:
             cap = (None if self._limit is None
                    else self._limit + self._offset)
@@ -937,8 +947,10 @@ class Query:
                     if cap is not None and got >= cap:
                         stream.close()  # early stop: cancels queued morsels
                         break
-            table = concat_tables(parts) if parts else self._empty_out(cp)
-            out = self._slice_limit(table)
+            with span("query.compute"):
+                table = (concat_tables(parts) if parts
+                         else self._empty_out(cp))
+                out = self._slice_limit(table)
         if opstats is not None:
             opstats["rows_out"] = out.num_rows
         return out
@@ -948,18 +960,23 @@ class Query:
                      opstats: Optional[dict] = None) -> Table:
         key_cols, spec = self._group_keys, self._agg_spec
         acc = _GroupedAcc(spec)
+
+        def partials(t: Table) -> _GroupPartial:
+            with span("query.compute"):
+                return _partial_groups(t, key_cols, spec)
+
         # partial aggregation runs inside the morsel workers (map_fn);
         # the merge below is the single-threaded consumer half
-        for partial in cp.plan.execute(
-                counters=counters,
-                map_fn=lambda t: _partial_groups(t, key_cols, spec)):
-            acc.merge(partial)
-        table = acc.to_table(key_cols, cp.schema)
-        if opstats is not None:
-            opstats["groups"] = table.num_rows
-        if self._order:
-            table = table.take(_sort_indices(table, self._order))
-        out = self._slice_limit(table)
+        for part in cp.plan.execute(counters=counters, map_fn=partials):
+            with span("query.compute"):
+                acc.merge(part)
+        with span("query.compute"):
+            table = acc.to_table(key_cols, cp.schema)
+            if opstats is not None:
+                opstats["groups"] = table.num_rows
+            if self._order:
+                table = table.take(_sort_indices(table, self._order))
+            out = self._slice_limit(table)
         if opstats is not None:
             opstats["rows_out"] = out.num_rows
         return out
@@ -973,7 +990,8 @@ class Query:
     # ------------------------------------------------------------ terminals
     def to_table(self) -> Table:
         """Execute and materialize the full result as one Table."""
-        return self._run(self._compile())
+        with query_span():
+            return self._run(self._compile())
 
     def to_pylist(self) -> List[dict]:
         """Execute and materialize as a list of row dicts."""
@@ -985,8 +1003,13 @@ class Query:
 
         Ordered or grouped queries materialize first (a sort/aggregation
         is a pipeline breaker); everything else streams, honoring
-        ``limit``/``offset`` with early scan termination.
+        ``limit``/``offset`` with early scan termination.  Each batch is
+        produced inside its own ``repro.query`` span.
         """
+        return rooted(self._iter_batches(batch_size))
+
+    def _iter_batches(self, batch_size: Optional[int]
+                      ) -> Generator[Table, None, None]:
         bs = batch_size or int(getattr(self._cfg, "batch_size", 131_072))
         if self._aggregated() or self._order:
             yield from rechunk(iter([self.to_table()]), bs)
@@ -1025,18 +1048,18 @@ class Query:
         projections don't change the row count, so they stay on the fast
         path too.  Grouped and ``distinct`` queries run the pipeline.
         """
-        if self._aggregated():
-            return self.to_table().num_rows
-        if not self._distinct:
-            man, schema = self._snapshot()
-            plan = AggregatePlan(man.files, self._db._reader_of, schema,
-                                 {"*": "count"}, filter_expr=self._where,
-                                 cfg=self._cfg, deltas=man.deltas,
-                                 partitioning=self._db._partitioning_of(man))
+        with query_span():
+            if self._aggregated() or self._distinct:
+                return self.to_table().num_rows
+            with span("query.plan"):
+                man, schema = self._snapshot()
+                plan = AggregatePlan(
+                    man.files, self._db._reader_of, schema, {"*": "count"},
+                    filter_expr=self._where, cfg=self._cfg, deltas=man.deltas,
+                    partitioning=self._db._partitioning_of(man))
             total = plan.execute()["*"]["count"]
             total = max(0, total - self._offset)
             return total if self._limit is None else min(total, self._limit)
-        return self.to_table().num_rows
 
     def agg(self, spec, explain: bool = False):
         """Ungrouped aggregate terminal — ``{column: {op: value}}``.
@@ -1060,16 +1083,23 @@ class Query:
         if self._aggregated():
             raise ValueError("agg() cannot follow group_by().agg(); the "
                              "query is already aggregated")
+        with query_span():
+            return self._agg(spec, explain)
+
+    def _agg(self, spec, explain: bool):
         simple = (not self._computed and not self._distinct
                   and not self._order and self._limit is None
                   and self._offset == 0)
-        man, schema = self._snapshot()
+        with span("query.plan"):
+            man, schema = self._snapshot()
+            if simple:
+                _normalize_spec(spec, schema)  # plan-build-time validation
+                plan = AggregatePlan(
+                    man.files, self._db._reader_of, schema, spec,
+                    filter_expr=self._where, cfg=self._cfg,
+                    deltas=man.deltas,
+                    partitioning=self._db._partitioning_of(man))
         if simple:
-            _normalize_spec(spec, schema)  # plan-build-time validation
-            plan = AggregatePlan(man.files, self._db._reader_of, schema,
-                                 spec, filter_expr=self._where,
-                                 cfg=self._cfg, deltas=man.deltas,
-                                 partitioning=self._db._partitioning_of(man))
             values = plan.execute()
             return (values, plan.report()) if explain else values
         q = self
@@ -1085,11 +1115,12 @@ class Query:
             table, report = q._run_reported()
         else:
             table = q.to_table()
-        norm = _normalize_spec(spec, table.schema)
-        acc = _GroupedAcc(norm)
-        if table.num_rows:
-            acc.merge(_partial_groups(table, [], norm))
-        values = acc.scalars()
+        with span("query.compute"):
+            norm = _normalize_spec(spec, table.schema)
+            acc = _GroupedAcc(norm)
+            if table.num_rows:
+                acc.merge(_partial_groups(table, [], norm))
+            values = acc.scalars()
         return (values, report) if explain else values
 
     # -------------------------------------------------------------- explain
@@ -1133,12 +1164,13 @@ class Query:
         ``limit`` scan *didn't* decode — and per-operator row counts are
         appended to the tree.
         """
-        if execute:
-            return self._run_reported()[1]
-        cp = self._compile()
+        with query_span():
+            if execute:
+                return self._run_reported()[1]
+            cp = self._compile()
+            scan = cp.plan.explain(execute=False)
         return QueryReport(ops=self._op_descriptions(),
-                           scan=cp.plan.explain(execute=False),
-                           executed=False)
+                           scan=scan, executed=False)
 
     def _run_reported(self) -> Tuple[Table, QueryReport]:
         """One execution that yields both the result and the full report."""

@@ -97,6 +97,7 @@ from typing import (Any, Callable, Dict, Generator, Iterable, List, Optional,
 import numpy as np
 
 from . import shm
+from ..spans import span
 from .backend import active_backend, set_backend
 from .expressions import Expr
 from .fileformat import TPQReader, page_codec_split
@@ -847,6 +848,10 @@ class ScanPlan:
         """
         if self._fragments is not None:
             return
+        with span("query.plan"):
+            self._plan_fragments()
+
+    def _plan_fragments(self) -> None:
         ov = self._overlay()
         c = ScanCounters()
         c.delta_files = len(self._deltas)
@@ -1361,7 +1366,8 @@ class ScanPlan:
                 counters.merge_from(local)  # single-threaded merge point
                 done = []
                 for t in tables:
-                    t = self._finish_table(t, frag, counters)
+                    with span("scan.morsel"):
+                        t = self._finish_table(t, frag, counters)
                     if t is not None:
                         done.append(t if map_fn is None else map_fn(t))
                 yield frag, done
@@ -1405,9 +1411,10 @@ class ScanPlan:
             # *before* the residual filter so it sees merged values
             t = ov.apply(t, counters)
         if self._expr is not None and not frag.pushdown:
-            mask = self._expr.evaluate(t)
-            if not mask.all():
-                t = t.filter_mask(mask)
+            with span("query.compute"):  # the residual filter
+                mask = self._expr.evaluate(t)
+                if not mask.all():
+                    t = t.filter_mask(mask)
         if t.num_rows:
             counters.rows_matched += t.num_rows
             # _emit_names keeps the id column while an ordered partition
@@ -1418,8 +1425,14 @@ class ScanPlan:
     def _fragment_tables(self, frag: FragmentPlan, counters: ScanCounters,
                          row_groups: Optional[List[int]] = None
                          ) -> Generator[Table, None, None]:
-        for t in self._decode_tables(frag, counters, row_groups):
-            t = self._finish_table(t, frag, counters)
+        tables = self._decode_tables(frag, counters, row_groups)
+        while True:
+            # one span per decoded table, closed before the yield
+            with span("scan.morsel"):
+                t = next(tables, None)
+                if t is None:
+                    return
+                t = self._finish_table(t, frag, counters)
             if t is not None:
                 yield t
 
