@@ -8,7 +8,8 @@ the main read path under the ``jax`` decode backend:
 - ``kernels``: every Pallas kernel against its jnp oracle at 65,536 values;
 - ``full_scan``: ``db.query().to_table()`` — fused morsel decode;
 - ``range_filter``: a selective range on a float32 column — two-phase,
-  per-page decode plus the ``filter_range`` kernel;
+  each column decoded in row-group batches, plus the ``filter_range``
+  kernel once per row group;
 - ``filtered_agg``: min/max/sum/mean/count over an id range that cuts two
   row groups, so partial groups decode and reduce through ``page_minmax``;
 - ``group_by``: a count per space group;

@@ -21,6 +21,7 @@ no selection quietly decodes on the host instead.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 from collections import Counter
@@ -60,6 +61,11 @@ class DecodeBackend:
         """Boolean mask for ``lo <= values <= hi`` (fused on device backends)."""
         return (values >= lo) & (values <= hi)
 
+    def covering(self, pages: int):
+        """Context in which this thread's :meth:`range_mask` calls each
+        cover ``pages`` pages (what a counting backend counts)."""
+        return contextlib.nullcontext()
+
     def minmax(self, values: np.ndarray):
         """(min, max) of a non-empty 1-D numeric array.
 
@@ -81,9 +87,10 @@ class JaxDecodeBackend(DecodeBackend):
     The kernels run compiled on an accelerator and in Pallas interpret mode
     on the CPU platform (``interpret``).  Every page the backend sees is
     counted where it ran: ``device_pages`` and ``host_pages`` map an
-    encoding name — or ``"filter"`` for a :meth:`range_mask` call (one page)
-    and ``"minmax"`` for a :meth:`minmax` call (one decoded batch) — to a
-    count, so a run can show that its work reached the device.
+    encoding name — or ``"filter"`` for the pages a :meth:`range_mask` call
+    covers (one, or what :meth:`covering` says) and ``"minmax"`` for a
+    :meth:`minmax` call (one decoded batch) — to a count, so a run can show
+    that its work reached the device.
     """
 
     name = "jax"
@@ -97,6 +104,7 @@ class JaxDecodeBackend(DecodeBackend):
         self.device_pages: Counter = Counter()
         self.host_pages: Counter = Counter()
         self._count_lock = threading.Lock()
+        self._covered = threading.local()
 
     def _count(self, on_device: bool, family: str, pages: int = 1) -> None:
         with self._count_lock:
@@ -198,6 +206,15 @@ class JaxDecodeBackend(DecodeBackend):
                 pos += n
         return out
 
+    @contextlib.contextmanager
+    def covering(self, pages: int):
+        prev = getattr(self._covered, "pages", 1)
+        self._covered.pages = pages
+        try:
+            yield
+        finally:
+            self._covered.pages = prev
+
     def range_mask(self, values: np.ndarray, lo, hi) -> np.ndarray:
         # the device sees 32-bit lanes and the kernel casts bounds through
         # float32, so both the column VALUES and the bounds must be exactly
@@ -211,12 +228,12 @@ class JaxDecodeBackend(DecodeBackend):
             exact = (self._fits_i32(lo, hi)
                      and max(abs(int(lo)), abs(int(hi))) < (1 << 24))
             if exact and dt.itemsize > 4 and len(values):
-                # wide columns route only when the page's actual values fit
+                # wide columns route only when the actual values fit
                 exact = self._fits_i32(values.min(), values.max())
         else:
             exact = False
         exact = exact and len(values) > 0
-        self._count(exact, "filter")
+        self._count(exact, "filter", getattr(self._covered, "pages", 1))
         if not exact:
             return super().range_mask(values, lo, hi)
         return self._ops.range_mask_on_device(values, lo, hi,

@@ -84,8 +84,8 @@ class Expr:
 
         ``lo``/``hi`` may be None (unbounded end); the ``*_open`` flags mark
         strict inequalities.  The two-phase reader converts the bounds to an
-        inclusive interval in the column's dtype and routes page-mask
-        evaluation through the decode backend's fused ``range_mask`` (the
+        inclusive interval in the column's dtype and routes the row-group
+        mask through the decode backend's fused ``range_mask`` (the
         Pallas ``filter_range`` kernel on the jax backend).  Must be
         *exact*: the converted mask on a fully-valid numeric column equals
         ``evaluate``'s mask.

@@ -591,7 +591,8 @@ class TPQReader:
         :class:`repro.core.scan.ScanCounters`) whose ``row_groups_scanned``,
         ``row_groups_skipped``, ``pages_scanned``, ``pages_skipped``,
         ``rows_scanned`` and ``bytes_decoded`` attributes are incremented as
-        the reader prunes and decodes.
+        the reader prunes and decodes, and whose late-materialization and
+        ``two_phase_pages_*`` attributes the two-phase read fills.
 
         ``verify`` is ``"page"`` (default — crc-check every stored buffer
         before decoding it, raising :class:`CorruptPageError` with
@@ -609,6 +610,13 @@ class TPQReader:
                         if c in self.schema]
                        if filter_expr is not None else [])
         two_phase = bool(filter_cols) and len(filter_cols) < len(names)
+        # a single-column contiguous range evaluates through the decode
+        # backend's fused range_mask (Pallas filter_range on the jax
+        # backend); any other predicate through Expr.evaluate
+        rng = (filter_expr.as_range()
+               if two_phase and len(filter_cols) == 1 else None)
+        if rng is not None and rng[0] != filter_cols[0]:
+            rng = None
         rg_sel = set(row_groups) if row_groups is not None else None
         for i, rg in enumerate(self.row_groups):
             if rg_sel is not None and i not in rg_sel:
@@ -637,15 +645,19 @@ class TPQReader:
                     first_chunk["pages"][j]["rows"] for j in page_sel) \
                     if first_chunk else 0
 
+            def fusable(name: str, idxs) -> bool:
+                # one batched decode covers these pages of the column
+                pages = rg["columns"][name]["pages"]
+                return (self.schema[name].dtype.kind == KIND_NUMERIC
+                        and not any("validity" in pages[j] for j in idxs))
+
             def read_pages(name: str, idxs, sels=None) -> Column:
                 pages = rg["columns"][name]["pages"]
                 if counters is not None:
                     counters.bytes_decoded += sum(
                         _page_stored_bytes(pages[j]) for j in idxs)
                 dtype = self.schema[name].dtype
-                if (sels is None and len(idxs) > 1
-                        and dtype.kind == KIND_NUMERIC
-                        and not any("validity" in pages[j] for j in idxs)):
+                if sels is None and len(idxs) > 1 and fusable(name, idxs):
                     # fused morsel decode: ONE batched backend dispatch per
                     # encoding group instead of one Python-level decode per
                     # page — the GIL-convoy fix for parallel scans (and it
@@ -669,78 +681,114 @@ class TPQReader:
                 return (concat_columns(pieces) if len(pieces) != 1
                         else pieces[0])
 
+            def note_batch(npages: int, batched: bool) -> None:
+                if counters is None:
+                    return
+                if batched and npages > 1:
+                    counters.two_phase_pages_batched += npages
+                else:
+                    counters.two_phase_pages_single += npages
+
             if two_phase:
-                # phase 1: decode ONLY the filter columns, page by page;
-                # a page with zero matches never touches the other columns.
-                # Each surviving page's mask becomes a *selection vector*:
-                # phase 2 materializes only the selected rows of the payload
-                # columns (late materialization — the page-slice and take
-                # are fused inside _read_column_page).
-                with span("reader.filter"):
-                    fschema = self.schema.select(filter_cols)
-                    # single-column contiguous ranges evaluate through the
-                    # decode backend's fused range_mask (Pallas filter_range
-                    # on the jax backend); else through Expr.evaluate
-                    rng = (filter_expr.as_range()
-                           if len(filter_cols) == 1 else None)
-                    if rng is not None and rng[0] != filter_cols[0]:
-                        rng = None
+                # phase 1: decode ONLY the filter columns, each in one batch
+                # over the row group's surviving pages, and evaluate the
+                # predicate once over the row group; a page with zero
+                # matches never touches the other columns.  Each kept
+                # page's share of the mask becomes a *selection vector*:
+                # phase 2 materializes only the selected rows of the
+                # payload columns (late materialization).
+                with span("reader.filter", pages=len(page_sel)):
+                    fcols = {}
+                    for n in filter_cols:
+                        fcols[n] = read_pages(n, page_sel)
+                        note_batch(len(page_sel), fusable(n, page_sel))
+                    mask = self._row_group_mask(filter_expr, filter_cols,
+                                                fcols, rng, len(page_sel))
+                    rows = [rg["columns"][filter_cols[0]]["pages"][j]["rows"]
+                            for j in page_sel]
                     kept: List[int] = []
                     sels: List[Optional[np.ndarray]] = []
-                    fcache: Dict[int, Dict[str, Column]] = {}
-                    for j in page_sel:
-                        fcols = {n: read_pages(n, [j])
-                                 for n in filter_cols}
-                        mask = None
-                        if rng is not None:
-                            fc = fcols[filter_cols[0]]
-                            if fc.dtype.kind == KIND_NUMERIC \
-                                    and fc.validity is None:
-                                bounds = _inclusive_bounds(rng,
-                                                           fc.values.dtype)
-                                if bounds is not None:
-                                    mask = np.asarray(
-                                        active_backend().range_mask(
-                                            fc.values, bounds[0],
-                                            bounds[1]), bool)
-                        if mask is None:
-                            mask = filter_expr.evaluate(
-                                Table(fschema, fcols))
-                        if mask.any():
+                    kept_rows: List[int] = []
+                    pos = 0
+                    for j, r in zip(page_sel, rows):
+                        m = mask[pos:pos + r]
+                        pos += r
+                        if m.any():
                             kept.append(j)
-                            sels.append(None if mask.all()
-                                        else np.nonzero(mask)[0])
-                            fcache[j] = fcols
+                            kept_rows.append(r)
+                            sels.append(None if m.all()
+                                        else np.flatnonzero(m))
                 if not kept:
                     continue
-                with span("reader.payload"):
+                with span("reader.payload", pages=len(kept)):
                     if counters is not None:
                         counters.rows_skipped_late += sum(
-                            len(fcache[j][filter_cols[0]]) - len(s)
-                            for j, s in zip(kept, sels) if s is not None)
+                            r - len(s) for r, s in zip(kept_rows, sels)
+                            if s is not None)
+                    # the selections laid end to end: over the phase-1
+                    # batch for filter columns, over the kept pages' batch
+                    # for batched payload columns
+                    fsel = None if mask.all() else np.flatnonzero(mask)
+                    ksel = None
+                    if any(s is not None for s in sels):
+                        starts = np.cumsum([0] + kept_rows[:-1])
+                        ksel = np.concatenate([
+                            np.arange(b, b + r) if s is None else s + b
+                            for b, r, s in zip(starts, kept_rows, sels)])
                     cols: Dict[str, Column] = {}
                     for name in names:
-                        if name in filter_cols:
-                            pieces = [fcache[j][name] if s is None
-                                      else fcache[j][name].take(s)
-                                      for j, s in zip(kept, sels)]
-                            cols[name] = (pieces[0] if len(pieces) == 1
-                                          else concat_columns(pieces))
+                        if name in fcols:
+                            c = fcols[name]
+                            cols[name] = c if fsel is None else c.take(fsel)
+                        elif fusable(name, kept):
+                            c = read_pages(name, kept)
+                            note_batch(len(kept), True)
+                            if ksel is not None:
+                                c = c.take(ksel)
+                                for r, s in zip(kept_rows, sels):
+                                    if s is not None:
+                                        _late_saved(counters, (r - len(s))
+                                                    * c.values.itemsize)
+                            cols[name] = c
                         else:
+                            # var-len, tensor, null and nullable columns:
+                            # page by page, the take fused into the decode
                             cols[name] = read_pages(name, kept, sels)
+                            note_batch(len(kept), False)
                     t = Table(sub_schema, cols)
             else:
-                with span("reader.payload"):
+                with span("reader.payload", pages=len(page_sel)):
                     cols = {name: read_pages(name, page_sel)
                             for name in names}
                     t = Table(sub_schema, cols)
                 if filter_expr is not None:
-                    with span("reader.filter"):
+                    with span("reader.filter", pages=len(page_sel)):
                         mask = filter_expr.evaluate(t)
                         if not mask.all():
                             t = t.filter_mask(mask)
             if t.num_rows:
                 yield t
+
+    def _row_group_mask(self, filter_expr: Expr, filter_cols: List[str],
+                        fcols: Dict[str, Column], rng: Optional[tuple],
+                        npages: int) -> np.ndarray:
+        """The predicate's mask over a row group's decoded filter columns.
+
+        ``rng`` is the predicate as one column's contiguous range (or
+        None): on a fully-valid numeric column it goes through the decode
+        backend's ``range_mask``, one call covering the ``npages`` pages.
+        """
+        if rng is not None:
+            fc = fcols[filter_cols[0]]
+            if fc.dtype.kind == KIND_NUMERIC and fc.validity is None:
+                bounds = _inclusive_bounds(rng, fc.values.dtype)
+                if bounds is not None:
+                    be = active_backend()
+                    with be.covering(npages):
+                        return np.asarray(be.range_mask(
+                            fc.values, bounds[0], bounds[1]), bool)
+        return filter_expr.evaluate(
+            Table(self.schema.select(filter_cols), fcols))
 
     def _select_pages(self, rg: int, expr: Expr, npages: int) -> List[int]:
         """Page-index pruning: keep pages whose aligned stats may match."""

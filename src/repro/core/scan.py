@@ -402,6 +402,11 @@ class ScanCounters:
     # pages decode to a transient and only the selection is kept
     rows_skipped_late: int = 0
     bytes_saved_late: int = 0
+    # two-phase reader: column pages decoded inside one row-group batch of
+    # two or more pages, and column pages decoded one at a time (a lone
+    # surviving page, or a var-len, tensor, null or nullable column)
+    two_phase_pages_batched: int = 0
+    two_phase_pages_single: int = 0
     # merge-on-read delta work (planning fills the first three from the
     # delta chain; execution fills applied/shadowed as rows are merged)
     delta_files: int = 0            # delta files in the overlaid chain
@@ -528,6 +533,11 @@ class ScanReport:
                     f"  late mat.:  {c.rows_skipped_late} payload rows "
                     f"skipped, {c.bytes_saved_late} value bytes kept out "
                     f"of result batches")
+            if c.two_phase_pages_batched or c.two_phase_pages_single:
+                lines.append(
+                    f"  two-phase:  {c.two_phase_pages_batched} column pages "
+                    f"decoded in row-group batches, "
+                    f"{c.two_phase_pages_single} one at a time")
         else:
             lines.append("  (planned only — pass execute=True for decode "
                          "counters)")

@@ -13,7 +13,9 @@ import numpy as np
 import pytest
 
 from repro.core import (LoadConfig, NormalizeConfig, ParquetDB, Table,
-                        TPQReader, field, write_table)
+                        TPQReader, backend, field, write_table)
+from repro.core.fileformat import _page_stored_bytes
+from repro.core.integrity import CorruptPageError
 from repro.core.scan import ScanCounters
 
 
@@ -252,3 +254,290 @@ class TestExplainReporting:
         full = db.read()
         oracle = full.filter_mask(expr.evaluate(full))
         assert norm(pruned.to_pylist()) == norm(oracle.to_pylist())
+
+
+# ---------------------------------------------------------------------------
+# Row-group batches: the two-phase read decodes each column's surviving
+# pages of a row group in one backend call, evaluates the predicate once
+# over the row group and takes the concatenated selection.  The reference
+# below is the same read page by page.
+# ---------------------------------------------------------------------------
+PAGE, GROUP = 64, 256
+COUNTED = ("row_groups_scanned", "row_groups_skipped", "pages_scanned",
+           "pages_skipped", "rows_scanned", "bytes_decoded",
+           "rows_skipped_late", "bytes_saved_late")
+
+
+def _per_page_read(path, expr):
+    """The two-phase read one page at a time, on the numpy reference:
+    filter columns decoded page by page, the predicate evaluated per page,
+    payload pages materialized through their own selection vectors."""
+    rd = TPQReader(path)
+    c = ScanCounters()
+    names = rd._project(None, expr)
+    fnames = [n for n in dict.fromkeys(expr.columns()) if n in rd.schema]
+    sub = rd.schema.select(names)
+    parts = []
+    for i, rg in enumerate(rd.row_groups):
+        if not expr.prune(rd.row_group_stats(i)):
+            c.row_groups_skipped += 1
+            continue
+        npages = len(rg["columns"][names[0]]["pages"])
+        page_sel = (rd._select_pages(i, expr, npages) if npages > 1
+                    else list(range(npages)))
+        if not page_sel:
+            c.row_groups_skipped += 1
+            c.pages_skipped += npages
+            continue
+        c.row_groups_scanned += 1
+        c.pages_scanned += len(page_sel)
+        c.pages_skipped += npages - len(page_sel)
+        for j in page_sel:
+            page = {n: rg["columns"][n]["pages"][j] for n in names}
+            c.rows_scanned += page[names[0]]["rows"]
+            fcols = {n: rd._read_column_page(page[n], rd.schema[n].dtype)
+                     for n in fnames}
+            c.bytes_decoded += sum(_page_stored_bytes(page[n])
+                                   for n in fnames)
+            mask = expr.evaluate(Table(rd.schema.select(fnames), fcols))
+            if not mask.any():
+                continue
+            sel = None if mask.all() else np.flatnonzero(mask)
+            if sel is not None:
+                c.rows_skipped_late += len(mask) - len(sel)
+            cols = {}
+            for n in names:
+                if n in fcols:
+                    cols[n] = fcols[n] if sel is None else fcols[n].take(sel)
+                else:
+                    c.bytes_decoded += _page_stored_bytes(page[n])
+                    cols[n] = rd._read_column_page(
+                        page[n], rd.schema[n].dtype, sel=sel, counters=c)
+            parts.append(Table(sub, cols))
+    return parts, c
+
+
+def _assert_same_table(got, parts):
+    assert got.num_rows == sum(p.num_rows for p in parts)
+    assert got.column_names == parts[0].column_names
+    assert norm(got.to_pylist()) == norm(
+        [r for p in parts for r in p.to_pylist()])
+    for name in got.column_names:
+        col = got.column(name)
+        if col.values is not None:  # fixed width: the same bytes
+            ref = np.concatenate([p.column(name).values for p in parts])
+            assert col.values.dtype == ref.dtype
+            assert col.values.tobytes() == ref.tobytes(), name
+
+
+def _numeric_table(n, seed=3):
+    rng = np.random.default_rng(seed)
+    return {
+        "x": rng.normal(0.0, 1.0, n).astype(np.float32),
+        "id": np.arange(n, dtype=np.int64) * 3 + 7,
+        "k": rng.integers(0, 1000, n).astype(np.int64),
+        "g": rng.choice(np.array([5, 11, 23, 42], np.int64), n),
+    }
+
+
+NUMERIC_ENC = {"x": "bss", "id": "delta", "k": "bitpack", "g": "dict"}
+
+
+def _case_f32_range(tmp_path):
+    t = Table.from_pydict(_numeric_table(4 * GROUP + 100))
+    p = str(tmp_path / "f32.tpq")
+    write_table(p, t, page_rows=PAGE, row_group_rows=GROUP,
+                field_encodings=NUMERIC_ENC)
+    return p, (field("x") >= np.float32(0.5)) & (field("x") <= np.float32(1.0))
+
+
+def _case_int64_conjunction(tmp_path):
+    t = Table.from_pydict(_numeric_table(3 * GROUP))
+    p = str(tmp_path / "conj.tpq")
+    write_table(p, t, page_rows=PAGE, row_group_rows=GROUP,
+                field_encodings=NUMERIC_ENC)
+    return p, (field("k") >= 100) & (field("k") < 160) & (field("g") != 23)
+
+
+def _case_zero_match_pages_between(tmp_path):
+    # pages 1 and 2 of each row group span the probe value in their stats
+    # but hold none of it, so page pruning keeps them and phase 1 drops them
+    n = 2 * GROUP
+    k = np.tile(np.arange(PAGE, dtype=np.int64) % 7 * 2, n // PAGE)
+    for rg in range(n // GROUP):
+        for pg in (0, 3):
+            k[rg * GROUP + pg * PAGE + 5] = 7
+    d = _numeric_table(n)
+    d["k"] = k
+    p = str(tmp_path / "gaps.tpq")
+    write_table(p, Table.from_pydict(d), page_rows=PAGE, row_group_rows=GROUP,
+                field_encodings=NUMERIC_ENC)
+    return p, field("k") == 7
+
+
+def _case_all_match_pages(tmp_path):
+    # ids grow, so pages past the bound match every row, one page partly
+    t = Table.from_pydict(_numeric_table(2 * GROUP))
+    p = str(tmp_path / "allmatch.tpq")
+    write_table(p, t, page_rows=PAGE, row_group_rows=GROUP,
+                field_encodings=NUMERIC_ENC)
+    return p, field("id") >= 3 * (GROUP + 40) + 7
+
+
+def _case_one_page_row_group(tmp_path):
+    t = Table.from_pydict(_numeric_table(2 * GROUP + 40))  # last rg: 1 page
+    p = str(tmp_path / "onepage.tpq")
+    write_table(p, t, page_rows=PAGE, row_group_rows=GROUP,
+                field_encodings=NUMERIC_ENC)
+    return p, field("x") > np.float32(-0.25)
+
+
+def _case_nullable_and_string_payload(tmp_path):
+    n = 2 * GROUP
+    d = _numeric_table(n)
+    rows = [{"x": float(d["x"][i]), "k": int(d["k"][i]),
+             "v": None if i % 5 == 0 else float(i),
+             "s": f"s{i % 9}" * (i % 4)} for i in range(n)]
+    t = Table.from_pylist(rows)
+    p = str(tmp_path / "mixed.tpq")
+    write_table(p, t, page_rows=PAGE, row_group_rows=GROUP)
+    return p, field("k") < 300
+
+
+def _case_all_null_page(tmp_path):
+    n = 2 * GROUP
+    d = _numeric_table(n)
+    rows = [{"k": int(d["k"][i]), "id": int(d["id"][i]),
+             "v": None if PAGE <= i % GROUP < 2 * PAGE else float(i)}
+            for i in range(n)]
+    p = str(tmp_path / "nullpage.tpq")
+    write_table(p, Table.from_pylist(rows), page_rows=PAGE,
+                row_group_rows=GROUP)
+    return p, field("k") < 250
+
+
+CASES = {
+    "f32_range_bss": _case_f32_range,
+    "int64_conjunction": _case_int64_conjunction,
+    "zero_match_pages_between": _case_zero_match_pages_between,
+    "all_match_pages": _case_all_match_pages,
+    "one_page_row_group": _case_one_page_row_group,
+    "nullable_and_string_payload": _case_nullable_and_string_payload,
+    "all_null_page": _case_all_null_page,
+}
+
+
+@pytest.fixture(params=["numpy", "jax"])
+def backend_name(request):
+    if request.param == "jax":
+        pytest.importorskip("jax")
+    yield request.param
+    backend.set_backend(None)
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_batched_two_phase_equals_per_page(tmp_path, backend_name, case):
+    path, expr = CASES[case](tmp_path)
+    backend.set_backend("numpy")
+    parts, want = _per_page_read(path, expr)
+    assert parts, "the case must match some rows"
+    backend.set_backend(backend_name)
+    got, c = _read(path, expr)
+    _assert_same_table(got, parts)
+    for k in COUNTED:
+        assert getattr(c, k) == getattr(want, k), k
+    # every column page decoded is counted once, batched or alone: the
+    # filter columns' surviving pages and the payload columns' kept pages
+    # (the reference yields one part per kept page)
+    fnames = set(expr.columns())
+    payload = len(TPQReader(path).schema.names) - len(fnames)
+    assert c.two_phase_pages_batched + c.two_phase_pages_single \
+        == len(fnames) * c.pages_scanned + payload * len(parts)
+
+
+def _count_device_calls(monkeypatch):
+    from repro.kernels import ops
+    calls = {"decode": 0, "range_mask": 0}
+
+    def counting(name, real):
+        def f(*a, **kw):
+            calls[name] += 1
+            return real(*a, **kw)
+        return f
+
+    monkeypatch.setattr(ops, "decode_batch_on_device",
+                        counting("decode", ops.decode_batch_on_device))
+    monkeypatch.setattr(ops, "range_mask_on_device",
+                        counting("range_mask", ops.range_mask_on_device))
+    return calls
+
+
+def test_one_device_call_per_column_per_row_group(tmp_path, monkeypatch):
+    pytest.importorskip("jax")
+    groups = 3
+    t = Table.from_pydict(_numeric_table(groups * GROUP))
+    p = str(tmp_path / "calls.tpq")
+    write_table(p, t, page_rows=PAGE, row_group_rows=GROUP,
+                field_encodings=NUMERIC_ENC)
+    backend.set_backend("jax")
+    try:
+        calls = _count_device_calls(monkeypatch)
+        expr = (field("x") >= np.float32(-0.5)) & (field("x") <= np.float32(0.5))
+        got, c = _read(p, expr)
+        oracle = t.filter_mask(expr.evaluate(t))
+        assert norm(got.to_pylist()) == norm(oracle.to_pylist())
+        pages = GROUP // PAGE
+        assert c.pages_scanned == groups * pages  # every page matches
+        # one decode per eligible column per row group, one range mask
+        assert calls == {"decode": groups * 4, "range_mask": groups}
+        assert c.two_phase_pages_batched == groups * pages * 4
+        assert c.two_phase_pages_single == 0
+
+        # a point lookup after page pruning: one page, a batch of one
+        calls.update(decode=0, range_mask=0)
+        got, c = _read(p, field("id") == 3 * (GROUP + 70) + 7)
+        assert got["id"].to_pylist() == [3 * (GROUP + 70) + 7]
+        assert c.pages_scanned == 1
+        assert calls == {"decode": 4, "range_mask": 1}
+        assert (c.two_phase_pages_batched, c.two_phase_pages_single) == (0, 4)
+        # the filter family counts the pages a range mask covers
+        be = backend.get_backend("jax")
+        before = be.device_pages["filter"]
+        _read(p, expr)
+        assert be.device_pages["filter"] - before == groups * pages
+    finally:
+        backend.set_backend(None)
+
+
+def test_explain_shows_two_phase_split(tmp_path):
+    n = 20_000
+    db = ParquetDB(os.path.join(str(tmp_path), "tp"))
+    db.create([{"a": i, "b": i * 2, "c": float(i)} for i in range(n)])
+    rep = db.explain(filters=[field("a") >= 10], execute=True)
+    assert rep.counters.two_phase_pages_batched > 0
+    assert rep.counters.two_phase_pages_single == 0
+    assert "two-phase:" in str(rep)
+    assert "two_phase_pages_batched" in rep.to_dict()["counters"]
+    assert "two-phase:" not in str(db.explain(execute=True))
+
+
+@pytest.mark.parametrize("backend_name", ["numpy", "jax"], indirect=True)
+def test_corrupt_payload_page_in_batch_keeps_coordinates(tmp_path,
+                                                         backend_name):
+    t = Table.from_pydict(_numeric_table(2 * GROUP))
+    p = str(tmp_path / "corrupt.tpq")
+    write_table(p, t, page_rows=PAGE, row_group_rows=GROUP,
+                field_encodings=NUMERIC_ENC)
+    target = next(buf for rg, col, page, key, buf
+                  in TPQReader(p).iter_page_buffers()
+                  if (rg, col, page, key) == (1, "id", 2, "values"))
+    with open(p, "r+b") as fh:
+        fh.seek(target["off"] + target["len"] // 2)
+        b = fh.read(1)
+        fh.seek(target["off"] + target["len"] // 2)
+        fh.write(bytes([b[0] ^ 0x40]))
+    backend.set_backend(backend_name)
+    with pytest.raises(CorruptPageError) as ei:
+        _read(p, field("x") > np.float32(-3.0))  # every page is kept
+    e = ei.value
+    assert (e.row_group, e.column, e.page) == (1, "id", 2)

@@ -4,7 +4,8 @@ Nothing runs: each kernel is lowered and compiled at real widths for a
 chip that is described, not attached, so a kernel the TPU compiler would
 refuse (a block off the tiling, an unsupported primitive) fails here with
 no chip.  Widths: a whole 65,536-value morsel, a 512-value morsel (shorter
-than one lane block) and a default 8,192-value page.
+than one lane block), a default 8,192-value page and a default
+131,072-row group (the two-phase reader's range mask).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library, and every test
@@ -24,6 +25,7 @@ from repro.kernels.segmented import (seg_bitunpack, seg_delta_decode,
 
 MORSEL = 65_536
 PAGE = 8_192
+ROW_GROUP = 131_072
 
 
 @pytest.fixture(scope="module")
@@ -102,6 +104,12 @@ def test_delta_decode(one_chip):
 def test_filter_range(one_chip, dtype):
     _compile(lambda x: filter_range(x, 3, 10),
              jax.ShapeDtypeStruct((PAGE,), dtype, sharding=one_chip))
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+def test_filter_range_row_group(one_chip, dtype):
+    _compile(lambda x: filter_range(x, 3, 10),
+             jax.ShapeDtypeStruct((ROW_GROUP,), dtype, sharding=one_chip))
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32, jnp.uint32])
