@@ -18,7 +18,7 @@ import sys
 import tempfile
 import time
 from collections import Counter
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -142,13 +142,23 @@ class Tracer:
             jax.profiler.stop_trace()
         return False
 
-    def reduce(self) -> Optional[dict]:
+    def reduce(self) -> Tuple[Optional[dict], Optional[dict]]:
+        """``(device, spans)`` from the one trace file of the window:
+        ``trace.py``'s reduction, and the ``layers_ms`` and ``queries`` of
+        ``program_spans.py``'s; each None where it finds nothing."""
         if not self.on:
-            return None
-        from . import trace
+            return None, None
+        from jax.profiler import ProfileData
+
+        from . import program_spans, trace
         found = [os.path.join(d, f) for d, _, fs in os.walk(self.dir)
                  for f in fs if f.endswith(".xplane.pb")]
-        return trace.reduce_file(found[0]) if found else None
+        if not found:
+            return None, None
+        data = ProfileData.from_file(found[0])
+        spans = program_spans.reduce_planes(data.planes)
+        return trace.reduce_planes(data.planes), spans and {
+            k: spans[k] for k in ("layers_ms", "queries")}
 
 
 def _span(name: str):
@@ -351,7 +361,7 @@ def measure(w: dict, seed: int, seconds: float, traced: bool,
             win[k] = {f: v for f, v in c.items() if v}
         win["memory_peak_bytes"] = memory_peak()
         del db
-        win["trace"] = tracer.reduce()
+        win["trace"], win["spans"] = tracer.reduce()
     return win
 
 
@@ -511,8 +521,12 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
     record = dict(win, closed=traffic["loop"] == "closed",
                   decode_families=DECODE_FAMILIES,
                   peaks=peaks()["devices"].get(device["kind"]))
-    print(json.dumps({k: win[k] for k in ("window_compiles", "query_s")
-                      + PAGES if k in win}), file=sys.stderr, flush=True)
+    side = {k: win[k] for k in ("window_compiles", "query_s",
+                                "required_values", "spans") + PAGES
+            if k in win}
+    if win["trace"]:
+        side["program_s"] = win["trace"]["program_s"]
+    print(json.dumps(side), file=sys.stderr, flush=True)
     for k, c in compared.items():
         lim = ("<= %r" % c["max"] if "max" in c else
                ">= %r" % c["min"] if "min" in c else "(not compared)")

@@ -1,0 +1,8 @@
+"""Copy back and device wait in the kernel wrappers, in ms per query:
+`repro.ops.fetch`, per `repro.query` root ending in the traced window
+(program_spans.py's `layers_ms`)."""
+from tpubench.program_spans import read_layer
+
+
+def read(r):
+    return read_layer(r, "fetch_ms")

@@ -162,6 +162,16 @@ def layers_ms(spans: Dict[str, float], queries: int
             for layer, names in LAYERS.items()}
 
 
+def read_layer(record: dict, layer: str) -> Optional[float]:
+    """One layer's ms per query from a run's record (its ``spans``, which
+    the harness keeps from this reduction), or None where the traced
+    window holds no ``repro.query`` root."""
+    spans = record.get("spans")
+    if not spans or not spans["layers_ms"]:
+        return None
+    return spans["layers_ms"][layer]
+
+
 def reduce_planes(planes) -> Optional[Dict]:
     """The reduction over planes as ``ProfileData`` gives them.  None when
     the trace has neither a window span nor a ``repro.query`` root."""

@@ -192,6 +192,8 @@ def device_values(q: dict, arrays: Dict[str, np.ndarray]) -> int:
     """Values of 32-bit device-routable columns that the query must
     produce: each filter column over every row, each other column it
     reads over the rows that match (all rows when there is no filter).
+    A computed column named in ``group_by``, ``agg`` or ``select`` is no
+    data column: the columns its expression reads are counted instead.
     A column is routable when it is float32, or integer with every value
     inside int32.  Used for the memory-bound floor of the device time."""
     def routable(a: np.ndarray) -> bool:
@@ -204,17 +206,15 @@ def device_values(q: dict, arrays: Dict[str, np.ndarray]) -> int:
     where = q.get("where")
     fcols = set(_columns(where)) if where is not None else set()
     matched = int(mask_of(where, arrays, n).sum())
+    computed = q.get("computed") or {}
     read = set()
     if q.get("group_by"):
         read |= set(q["group_by"]) | {c for c in q["agg"] if c != "*"}
-    if q.get("computed"):
-        for e in q["computed"].values():
-            read |= set(_columns(e))
+    for e in computed.values():
+        read |= set(_columns(e))
     sel = q.get("select") or []
-    if sel == ["*"]:
-        read |= set(arrays)
-    elif not q.get("computed"):
-        read |= set(sel)
+    read |= set(arrays) if sel == ["*"] else set(sel)
+    read -= set(computed)
     total = 0
     for c in fcols | read:
         if routable(arrays[c]):

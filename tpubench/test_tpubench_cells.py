@@ -12,7 +12,8 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from tpubench import control, drive, harness, reference, spec  # noqa: E402
+from tpubench import (control, drive, harness, program_spans,  # noqa: E402
+                      reference, spec)
 
 ROWS = 20_000
 SEED = 3_000_000_019  # more than 32 bits: seeds may be that large
@@ -146,7 +147,7 @@ def test_benchmark_names_its_metric_readers_and_traffic():
         assert os.path.exists(f"{spec.HERE}/traffic/{w['traffic']}.json")
 
 
-def test_traced_run_reports_per_layer_metrics_only():
+def test_traced_run_reports_per_layer_metrics_only(capsys):
     out = _run("alexandria.scan", traced=True)
     assert _sound(out), out["checks"]
     per_layer = {m["name"] for m in spec.benchmark()["per_layer"]}
@@ -154,6 +155,15 @@ def test_traced_run_reports_per_layer_metrics_only():
     # the CPU has no device trace; the backend's counters still read
     assert "host_page_share.query" in out["metrics"]
     assert {"busy_s", "window_s"} <= set(out["device"])
+    # the store's spans are on the host's lines of the same trace
+    layers = {m: v["value"] for m, v in out["metrics"].items()
+              if m.endswith("_ms.query")}
+    assert set(layers) == {n + ".query" for n in program_spans.LAYERS}
+    assert layers["reader_ms.query"] > 0 and layers["stage_ms.query"] > 0
+    side = json.loads(next(x for x in capsys.readouterr().err.splitlines()
+                           if x.startswith("{")))
+    assert side["spans"]["queries"] == out["attempted"]
+    assert side["required_values"] > 0
 
 
 def test_run_refuses_without_a_tpu(capsys, monkeypatch):

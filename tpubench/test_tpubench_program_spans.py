@@ -131,6 +131,18 @@ def test_a_layer_reads_its_spans(layer):
     assert ps.layers_ms(spans, 0) is None
 
 
+@pytest.mark.parametrize("layer", sorted(ps.LAYERS))
+def test_a_layer_metric_reads_the_record_or_nothing(layer):
+    spans = {n: 0.001 * (1 + i) for i, n in enumerate(EVERY)}
+    kept = {"layers_ms": ps.layers_ms(spans, 4), "queries": 4}
+    assert ps.read_layer({"spans": kept}, layer) == kept["layers_ms"][layer]
+    # an untraced run, a trace with no repro.query root in the window
+    assert ps.read_layer({"spans": None}, layer) is None
+    assert ps.read_layer({}, layer) is None
+    none = {"layers_ms": ps.layers_ms(spans, 0), "queries": 0}
+    assert ps.read_layer({"spans": none}, layer) is None
+
+
 def test_the_layers_partition_the_program_spans():
     assert len(EVERY) == len(set(EVERY))
     spans = {n: 0.002 * (1 + i) for i, n in enumerate(EVERY)}

@@ -9,6 +9,10 @@ benchmark reports.
 - ``device_ops``: the operations that took most device time, named
   ``<program>:<operation>`` (the program from the ``XLA Modules`` line
   without its fingerprint, the operation's HLO name).
+- ``program_s``: every program's device time, the time of its operations
+  inside the span averaged over the devices as ``busy_s`` is, named as in
+  ``device_ops``; so a kernel's time is found by its ``jit_`` name
+  whatever its rank.
 - ``idle_gaps``: device idle time inside the span, summed by what the
   host was doing: the innermost ``tpubench.*`` span open at the middle of
   the gap, then the innermost other host event open there, if any.
@@ -121,6 +125,7 @@ def reduce_planes(planes) -> Optional[Dict]:
     lo, hi = window
     busy_total, used, launches = 0, 0, 0
     op_time: Dict[str, int] = defaultdict(int)
+    prog_time: Dict[str, int] = defaultdict(int)
     idle: Dict[str, int] = defaultdict(int)
     bench_spans, host_spans = _Spans(bench), _Spans(host)
     for plane in devices:
@@ -137,8 +142,10 @@ def reduce_planes(planes) -> Optional[Dict]:
             if e > lo and s < hi:
                 intervals.append((s, e))
                 prog = programs.innermost(s)
-                op_time[(prog + ":" if prog else "") + _op(name)] += \
-                    min(e, hi) - max(s, lo)
+                dt = min(e, hi) - max(s, lo)
+                op_time[(prog + ":" if prog else "") + _op(name)] += dt
+                if prog:
+                    prog_time[prog] += dt
         if not intervals:
             continue
         used += 1
@@ -160,6 +167,7 @@ def reduce_planes(planes) -> Optional[Dict]:
         "devices": used,
         "device_ops": [[n, t / 1e9] for n, t in top],
         "idle_gaps": [[n, t / 1e9] for n, t in top_idle],
+        "program_s": {n: t / used / 1e9 for n, t in sorted(prog_time.items())},
     }
 
 
