@@ -30,10 +30,15 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from . import encodings as enc
+from .dtypes import KIND_NUMERIC
+from .expressions import _ARITH_FNS, Arith, FieldRef
 
 ENV_VAR = "REPRO_DECODE_BACKEND"
 
 _INT32_MIN, _INT32_MAX = -(1 << 31), (1 << 31) - 1
+# a morsel's grouped sums go to the device only with this many groups or
+# fewer; more go to the host, which needs no slot per group
+MAX_DEVICE_GROUPS = 64
 
 
 class DecodeBackend:
@@ -75,6 +80,104 @@ class DecodeBackend:
         """
         return values.min(), values.max()
 
+    def group_sums(self, codes: np.ndarray, groups: int, terms, table):
+        """Per-group row counts and exact integer sums of ``terms`` over
+        ``table``'s rows, where row *i* is in group ``codes[i]``: the
+        grouped path's reduction.  ``terms`` are value expressions
+        (``FieldRef`` or ``Arith``) over ``table``'s columns, ``None`` for
+        one that only the host can reduce.  Returns ``(counts, sums)``,
+        each sum in the dtype the host's own reduction gives, or None: the
+        caller then reduces the whole morsel on the host."""
+        return None
+
+
+class _HostOnly(Exception):
+    """A grouped sum the device cannot reproduce exactly."""
+
+
+def _sbits(lo: int, hi: int) -> int:
+    """Two's-complement bits that hold every integer in ``[lo, hi]``."""
+    return 1 + max((v if v >= 0 else -v - 1).bit_length() for v in (lo, hi))
+
+
+def _sum_plan(terms, table):
+    """``(programs, leaves, dtypes)`` for ``group_reduce``, or None.
+
+    Each leaf column must be integer, fully valid and inside int32 in this
+    morsel.  Each term is walked with interval bounds from the leaves'
+    minima and maxima, and its result dtype taken from numpy's own rules
+    for the host's evaluation: every node must be integer and inside its
+    dtype, so the host's arithmetic does not wrap and the device's exact
+    value is the host's.  A term carries enough 13-bit limbs for the
+    widest of its nodes, and at least ``MIN_LIMBS``.
+    """
+    from repro.kernels.group_reduce import LIMB_BITS, MIN_LIMBS
+    index: dict = {}
+    leaves: List[np.ndarray] = []
+    bounds: List[tuple] = []
+
+    def leaf(name: str):
+        if name not in index:
+            col = table.column(name)
+            v = col.values
+            if (col.dtype.kind != KIND_NUMERIC or v.dtype.kind not in "iu"
+                    or (col.validity is not None and not col.validity.all())):
+                raise _HostOnly
+            lo, hi = int(v.min()), int(v.max())
+            if not JaxDecodeBackend._fits_i32(lo, hi):
+                raise _HostOnly
+            index[name] = len(leaves)
+            leaves.append(v)
+            bounds.append((lo, hi))
+        i = index[name]
+        return ("x", i), bounds[i][0], bounds[i][1], leaves[i][:0], 0
+
+    def walk(e):
+        """``(program, lo, hi, empty array of the node's dtype or the
+        constant itself, widest bits below)``."""
+        if isinstance(e, FieldRef):
+            return leaf(e.name)
+        if isinstance(e, (int, np.integer)) and not isinstance(
+                e, (bool, np.bool_)):
+            return ("c", int(e)), int(e), int(e), e, 0
+        if not isinstance(e, Arith) or e.op not in ("+", "-", "*"):
+            raise _HostOnly
+        pa, la, ha, va, ba = walk(e.a)
+        pb, lb, hb, vb, bb = walk(e.b)
+        if pa[0] == "c" and pb[0] == "c":
+            raise _HostOnly  # a constant node: the host broadcasts it
+        try:
+            out = _ARITH_FNS[e.op](va, vb)
+        except (OverflowError, TypeError):
+            raise _HostOnly from None
+        if e.op == "+":
+            lo, hi = la + lb, ha + hb
+        elif e.op == "-":
+            lo, hi = la - hb, ha - lb
+        else:
+            ends = (la * lb, la * hb, ha * lb, ha * hb)
+            lo, hi = min(ends), max(ends)
+        if out.dtype.kind not in "iu":
+            raise _HostOnly
+        info = np.iinfo(out.dtype)
+        if lo < info.min or hi > info.max:
+            raise _HostOnly  # the host's own arithmetic would wrap
+        bits = max(ba, bb, _sbits(la, ha), _sbits(lb, hb), _sbits(lo, hi))
+        return (e.op, pa, pb), lo, hi, out, bits
+
+    programs, dtypes = [], []
+    try:
+        for t in terms:
+            if t is None:
+                return None
+            prog, lo, hi, empty, bits = walk(t)
+            bits = max(bits, _sbits(lo, hi))
+            programs.append((prog, max(MIN_LIMBS, -(-bits // LIMB_BITS))))
+            dtypes.append(empty.dtype)
+    except _HostOnly:
+        return None
+    return tuple(programs), leaves, dtypes
+
 
 class JaxDecodeBackend(DecodeBackend):
     """Routes safe pages through the Pallas decode kernels.
@@ -90,7 +193,8 @@ class JaxDecodeBackend(DecodeBackend):
     encoding name — or ``"filter"`` for the pages a :meth:`range_mask` call
     covers (one, or what :meth:`covering` says) and ``"minmax"`` for a
     :meth:`minmax` call (one decoded batch) — to a count, so a run can show
-    that its work reached the device.
+    that its work reached the device.  ``"group_rows"`` counts the rows a
+    :meth:`group_sums` call reduced, on the device or on the host.
     """
 
     name = "jax"
@@ -253,6 +357,24 @@ class JaxDecodeBackend(DecodeBackend):
         if not routable:
             return super().minmax(values)
         return self._ops.minmax_on_device(values, interpret=self.interpret)
+
+    def group_sums(self, codes: np.ndarray, groups: int, terms, table):
+        # the gate: few groups, and every term an exact integer program
+        # over fully valid int32 leaves (see _sum_plan)
+        plan = (_sum_plan(terms, table)
+                if 0 < groups <= MAX_DEVICE_GROUPS and len(codes) else None)
+        if plan is not None and (self._ops.pad_groups(groups)
+                                 * self._ops.group_slots(plan[0])
+                                 > self._ops.MAX_GROUP_SLOTS):
+            plan = None
+        self._count(plan is not None, "group_rows", len(codes))
+        if plan is None:
+            return None
+        programs, leaves, dtypes = plan
+        counts, sums = self._ops.group_sums_on_device(
+            codes, groups, leaves, programs, interpret=self.interpret)
+        # the host sums in each term's own dtype, wrapping alike
+        return counts, [s.astype(dt) for s, dt in zip(sums, dtypes)]
 
 
 _jax_probe: Optional[bool] = None

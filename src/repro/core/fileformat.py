@@ -445,6 +445,16 @@ class TPQReader:
         return active_backend().decode(buf["enc"], buf.get("meta", {}),
                                        payload, buf["count"], np_dtype)
 
+    def _read_lengths(self, buf: dict, verify: bool = False,
+                      ctx: tuple = ()) -> np.ndarray:
+        """A var-len page's value lengths, decoded here on the host: they
+        only index the blob, which is read here too, and a device round
+        trip for one page's few kilobytes of lengths costs more than the
+        decode."""
+        payload = self._get(buf, verify=verify, ctx=ctx)
+        return enc.decode(buf["enc"], buf.get("meta", {}), payload,
+                          buf["count"], np.int64)
+
     # -- scrubbing ---------------------------------------------------------------
     def iter_page_buffers(self) -> Iterator[tuple]:
         """Yield ``(row_group, column, page, key, buf)`` for every stored
@@ -520,8 +530,8 @@ class TPQReader:
                             * int(np.prod(dtype.shape)))
             return Column(dtype, values=vals, validity=validity)
         if k in (KIND_STRING, KIND_BINARY):
-            lens = self._read_values(page["lengths"], np.int64,
-                                     verify=verify, ctx=ctx)
+            lens = self._read_lengths(page["lengths"], verify=verify,
+                                      ctx=ctx)
             offsets = np.zeros(rows + 1, np.int64)
             np.cumsum(lens, out=offsets[1:])
             blob = np.frombuffer(
@@ -533,8 +543,8 @@ class TPQReader:
                               validity=validity)
             return Column(dtype, offsets=offsets, blob=blob, validity=validity)
         if k == KIND_LIST:
-            lens = self._read_values(page["lengths"], np.int64,
-                                     verify=verify, ctx=ctx)
+            lens = self._read_lengths(page["lengths"], verify=verify,
+                                      ctx=ctx)
             offsets = np.zeros(rows + 1, np.int64)
             np.cumsum(lens, out=offsets[1:])
             if sel is not None:

@@ -64,6 +64,7 @@ import numpy as np
 from . import nested
 from ..spans import query_span, rooted, span
 from .aggregate import AggregatePlan, _normalize_spec
+from .backend import active_backend
 from .dtypes import DType, KIND_NULL, KIND_NUMERIC, KIND_STRING
 from .expressions import (And, Arith, Comparison, Expr, FieldRef, IsIn,
                           IsNaN, IsNull, Not, Or)
@@ -166,21 +167,96 @@ def _canon_computed(ve: Any) -> str:
 # ---------------------------------------------------------------------------
 # hash grouping: numpy factorize + segmented reduction
 # ---------------------------------------------------------------------------
+def _sorted_codes(vals: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """``np.unique(vals, return_inverse=True)``; integers over a span not
+    much wider than their count go through a dense table instead of a
+    sort."""
+    if vals.dtype.kind in "iu" and len(vals):
+        lo = vals.min()
+        if int(vals.max()) - int(lo) < 2 * len(vals) + 4096:
+            # offsets from the minimum, in a width that cannot wrap
+            wide = np.int64 if vals.dtype.kind == "i" else np.uint64
+            at = (vals.astype(wide) - wide(lo)).astype(np.intp)
+            seen = np.zeros(int(at.max()) + 1, bool)
+            seen[at] = True
+            rank = np.cumsum(seen) - 1
+            u = np.flatnonzero(seen).astype(wide) + wide(lo)
+            return u.astype(vals.dtype), rank[at]
+    return np.unique(vals, return_inverse=True)
+
+
+def _string_codes(col: Column, valid: Optional[np.ndarray]
+                  ) -> Optional[Tuple[np.ndarray, List[str]]]:
+    """(codes, keys) of a string column's valid rows (``valid`` None: all)
+    from its offsets and blob, keys in code-point order as Python's
+    ``str`` sorts them (UTF-8 bytes compare alike).  Each value is its
+    bytes, zero-padded, then its length, which ties a value to any longer
+    one it is a prefix of: up to seven bytes that is one integer (no
+    length where every value has the same), longer ones sort by
+    big-endian words.  None when padding every value would take far more
+    room than the blob (a few very long values)."""
+    off = col.offsets
+    starts, lens = off[:-1], np.diff(off)
+    if valid is not None:
+        starts, lens = starts[valid], lens[valid]
+    nv = len(lens)
+    blob = np.ascontiguousarray(col.blob)
+    wmax = int(lens.max()) if nv else 0
+    if wmax <= 7:
+        same = bool(nv) and int(lens.min()) == wmax
+        key = np.zeros(nv, np.uint64)
+        for j in range(wmax):
+            if same:
+                b = blob[starts + j]
+            else:
+                b = np.zeros(nv, np.uint8)
+                has = lens > j
+                b[has] = blob[starts[has] + j]
+            key = (key << np.uint64(8)) | b
+        if not same:
+            key = (key << np.uint64(3)) | lens.astype(np.uint64)
+        u, inv = _sorted_codes(key)
+        keys = [(x if same else x >> 3).to_bytes(wmax, "big")
+                [:wmax if same else x & 7].decode("utf-8")
+                for x in u.tolist()]
+        return inv, keys
+    width = -(-wmax // 8) * 8
+    if nv * width > 16 * len(blob) + (1 << 20):
+        return None
+    pad = np.zeros((nv, width), np.uint8)
+    row = np.repeat(np.arange(nv), lens)
+    new_off = np.zeros(nv + 1, np.int64)
+    np.cumsum(lens, out=new_off[1:])
+    pos = np.arange(len(row)) - new_off[:-1][row]
+    pad[row, pos] = blob[starts[row] + pos]
+    words = pad.view(">u8")
+    order = np.lexsort([lens] + [words[:, j]
+                                 for j in range(width // 8 - 1, -1, -1)])
+    w, ln = words[order], lens[order]
+    new = np.r_[True, (w[1:] != w[:-1]).any(axis=1) | (ln[1:] != ln[:-1])]
+    inv = np.empty(nv, np.int64)
+    inv[order] = np.cumsum(new) - 1
+    first = order[np.flatnonzero(new)]
+    keys = [bytes(blob[starts[i]:starts[i] + lens[i]]).decode("utf-8")
+            for i in first]
+    return inv, keys
+
+
 def _factorize(col: Column) -> Tuple[np.ndarray, List[Any]]:
     """Per-column dictionary encoding: (codes[n], keys) with keys[codes[i]]
-    the python key value of row i.  Null rows form one group, float-NaN
-    rows another (keyed by the ``_NAN_KEY`` singleton)."""
+    the python key value of row i, keys sorted.  Null rows form one group,
+    float-NaN rows another (keyed by the ``_NAN_KEY`` singleton)."""
     n = len(col)
     k = col.dtype.kind
     if k == KIND_NULL:
         return np.zeros(n, np.int64), [None]
     if k == KIND_NUMERIC:
-        vals = col.values
         valid = np.ones(n, bool) if col.validity is None else col.validity
+        vals = col.values
         nan = (np.isnan(vals) & valid if vals.dtype.kind == "f"
                else np.zeros(n, bool))
         ok = valid & ~nan
-        u, inv = np.unique(vals[ok], return_inverse=True)
+        u, inv = _sorted_codes(vals[ok])
         codes = np.zeros(n, np.int64)
         codes[ok] = inv
         keys: List[Any] = [v.item() for v in u]
@@ -192,15 +268,20 @@ def _factorize(col: Column) -> Tuple[np.ndarray, List[Any]]:
             keys.append(None)
         return codes, keys
     if k == KIND_STRING:
-        pl = col.to_pylist()
-        valid = np.array([v is not None for v in pl], bool)
-        present = np.array([v for v in pl if v is not None], dtype=object)
-        u, inv = np.unique(present, return_inverse=True)
-        codes = np.zeros(n, np.int64)
+        valid = col.validity
+        got = _string_codes(col, valid)
+        if got is None:  # a few very long values: sort them as objects
+            pl = col.to_pylist()
+            present = np.array([v for v in pl if v is not None],
+                               dtype=object)
+            u, inv = np.unique(present, return_inverse=True)
+            got = inv, list(u)
+        inv, keys = got
+        if valid is None:
+            return inv.astype(np.int64, copy=False), keys
+        codes = np.full(n, len(keys), np.int64)
         codes[valid] = inv
-        keys = list(u)
         if not valid.all():
-            codes[~valid] = len(keys)
             keys.append(None)
         return codes, keys
     raise TypeError(f"cannot group/dedupe on a {col.dtype} column")
@@ -227,7 +308,7 @@ def _row_codes(t: Table, key_cols: Sequence[str]
     for codes_i, keys_i in per[1:]:
         card = max(len(keys_i), 1)
         combined = codes * card + codes_i
-        u, inv = np.unique(combined, return_inverse=True)
+        u, inv = _sorted_codes(combined)
         keys_out = [keys_out[c // card] + (keys_i[c % card],)
                     for c in u.tolist()]
         codes = inv.astype(np.int64, copy=False)
@@ -243,18 +324,52 @@ class _GroupPartial:
         self.keys, self.rows, self.cols = keys, rows, cols
 
 
+def _device_partial(t: Table, codes: np.ndarray, g: int,
+                    spec: Dict[str, List[str]],
+                    computed: Dict[str, Any]
+                    ) -> Optional[Tuple[np.ndarray, Dict[str, dict]]]:
+    """``(rows, columns)`` of a partial from the decode backend's grouped
+    sums, or None where the backend leaves the morsel to the host.  A column's
+    term is its computed expression or the column itself; one with a
+    ``min`` or ``max`` has none (they are not sums)."""
+    names = [c for c in spec if c != "*"]
+    terms = [None if {"min", "max"} & set(spec[c])
+             else computed.get(c, FieldRef(c)) for c in names]
+    got = active_backend().group_sums(codes, g, terms, t)
+    if got is None:
+        return None
+    counts, sums = got
+    cols: Dict[str, dict] = {}
+    for c, total in zip(names, sums):
+        entry = {"count": counts, "vcount": counts}
+        if "sum" in spec[c] or "mean" in spec[c]:
+            entry["sum"] = total
+        cols[c] = entry
+    return counts, cols
+
+
 def _partial_groups(t: Table, key_cols: Sequence[str],
-                    spec: Dict[str, List[str]]) -> _GroupPartial:
+                    spec: Dict[str, List[str]],
+                    computed: Sequence[Tuple[str, Any]] = ()
+                    ) -> _GroupPartial:
     """Factorize one batch and reduce every aggregate column per group.
 
-    Vectorized: group codes from :func:`_row_codes`, then per column one
-    stable sort + ``ufunc.reduceat`` segmented reduction (sum keeps the
-    source dtype, so int64 sums do not round-trip through float).
+    ``computed`` defines aggregate columns that are not in ``t``: where
+    the decode backend takes the sums (integer inputs, few groups), it
+    forms them from their inputs itself; otherwise they are evaluated
+    here, and every column is reduced by one stable sort +
+    ``ufunc.reduceat`` segmented reduction (sum keeps the source dtype,
+    so int64 sums do not round-trip through float).
     """
     codes, keys = _row_codes(t, key_cols)
     g = len(keys)
+    got = _device_partial(t, codes, g, spec, dict(computed))
+    if got is not None:
+        return _GroupPartial(keys, *got)
+    for name, ve in computed:
+        t = t.set_column(name, ve.evaluate_column(t))
     rows = np.bincount(codes, minlength=g)
-    cols: Dict[str, dict] = {}
+    cols = {}
     for col, ops in spec.items():
         if col == "*":
             continue
@@ -291,24 +406,26 @@ def _partial_groups(t: Table, key_cols: Sequence[str],
                     entry["min"][gids] = np.minimum.reduceat(xx, starts)
                     entry["max"][gids] = np.maximum.reduceat(xx, starts)
         elif c.dtype.kind == KIND_STRING:
-            pl = c.to_pylist()
-            valid = np.array([v is not None for v in pl], bool)
+            valid = (np.ones(len(c), bool) if c.validity is None
+                     else c.validity)
             entry["count"] = np.bincount(codes[valid], minlength=g)
             entry["vcount"] = entry["count"]
             if need_mm:
+                # the column's own codes are ranks of its sorted values
                 amn = np.full(g, None, object)
                 amx = np.full(g, None, object)
                 sel = np.nonzero(valid)[0]
                 if len(sel):
+                    ranks, values = _factorize(c)
                     order = np.argsort(codes[sel], kind="stable")
                     cc = codes[sel][order]
-                    ss = [pl[i] for i in sel[order]]
+                    rr = ranks[sel][order]
                     starts = np.nonzero(np.r_[True, cc[1:] != cc[:-1]])[0]
-                    bounds = list(starts) + [len(ss)]
-                    for j, s in enumerate(starts):
-                        seg = ss[s:bounds[j + 1]]
-                        amn[cc[s]] = min(seg)
-                        amx[cc[s]] = max(seg)
+                    gids = cc[starts]
+                    amn[gids] = [values[r] for r in
+                                 np.minimum.reduceat(rr, starts)]
+                    amx[gids] = [values[r] for r in
+                                 np.maximum.reduceat(rr, starts)]
                 entry["min"], entry["max"] = amn, amx
         else:
             # null column (schema-evolved rows) or count over exotic types:
@@ -564,12 +681,28 @@ class GroupedQuery:
     def agg(self, spec) -> "Query":
         """Aggregate each group; ``spec`` maps column (or ``"*"``) to one
         op or a list of ops from ``("count", "min", "max", "sum",
-        "mean")``.  The result relation has the group-key columns plus one
+        "mean")``: a stored column or one that ``select(**computed)``
+        defined.  The result relation has the group-key columns plus one
         ``{col}_{op}`` column per aggregate (``count`` for ``"*"``), and
         composes with ``order_by`` / ``limit`` / ``offset``."""
         q = self._query
-        norm = _normalize_spec(spec, q._schema())
+        norm = _normalize_spec(spec, _agg_schema(q._schema(), q._computed))
         return q._replace(group_keys=list(self._keys), agg_spec=norm)
+
+
+def _agg_schema(schema: Schema, computed: Sequence[Tuple[str, Any]]
+                ) -> Schema:
+    """``schema`` with each computed column in it, typed as its
+    expression evaluates over no rows; a computed name shadows a stored
+    one, as ``select`` does."""
+    if not computed:
+        return schema
+    names = {n for n, _ in computed}
+    fields = [f for f in schema if f.name not in names]
+    for name, ve in computed:
+        empty = Table.empty(schema.select(list(dict.fromkeys(ve.columns()))))
+        fields.append(Field(name, ve.evaluate_column(empty).dtype))
+    return Schema(fields)
 
 
 class Query:
@@ -819,8 +952,10 @@ class Query:
                    else self._output_names(schema))
         # a computed column dropped by a later positional select() is dead:
         # don't decode its inputs or evaluate it per batch (order_by keys
-        # are always output columns, so this can never drop a sort key)
-        computed = [(n, ve) for n, ve in self._computed if n in out_pre]
+        # are always output columns, so this can never drop a sort key);
+        # a grouped query keeps those it aggregates
+        live = self._agg_spec if self._aggregated() else out_pre
+        computed = [(n, ve) for n, ve in self._computed if n in live]
         computed_names = {n for n, _ in computed}
         scan_cols: List[str] = []
 
@@ -832,7 +967,10 @@ class Query:
             for kcol in self._group_keys:
                 need(kcol)
             for col in self._agg_spec:
-                if col != "*":
+                if col in computed_names:
+                    for c in dict(computed)[col].columns():
+                        need(c)
+                elif col != "*":
                     need(col)
             if not scan_cols:
                 # count(*)-only grouped spec still needs one physical
@@ -963,7 +1101,7 @@ class Query:
 
         def partials(t: Table) -> _GroupPartial:
             with span("query.compute"):
-                return _partial_groups(t, key_cols, spec)
+                return _partial_groups(t, key_cols, spec, cp.computed)
 
         # partial aggregation runs inside the morsel workers (map_fn);
         # the merge below is the single-threaded consumer half
@@ -971,7 +1109,8 @@ class Query:
             with span("query.compute"):
                 acc.merge(part)
         with span("query.compute"):
-            table = acc.to_table(key_cols, cp.schema)
+            table = acc.to_table(key_cols,
+                                 _agg_schema(cp.schema, cp.computed))
             if opstats is not None:
                 opstats["groups"] = table.num_rows
             if self._order:

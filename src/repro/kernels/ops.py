@@ -15,11 +15,11 @@ caller passes ``interpret=True`` (the decode backend does so on the CPU
 platform).
 
 Every device call the store makes goes through this module: the morsel
-and page decodes, ``range_mask_on_device`` and ``minmax_on_device``.  Each
-opens three spans (:mod:`repro.spans`): ``repro.ops.stage`` around the
-host-side staging, ``repro.ops.launch`` around the jitted call with its
-implicit upload, and ``repro.ops.fetch`` around the blocking copy back,
-which includes waiting for the device.
+and page decodes, ``range_mask_on_device``, ``minmax_on_device`` and
+``group_sums_on_device``.  Each opens three spans (:mod:`repro.spans`):
+``repro.ops.stage`` around the host-side staging, ``repro.ops.launch``
+around the jitted call with its implicit upload, and ``repro.ops.fetch``
+around the blocking copy back, which includes waiting for the device.
 """
 from __future__ import annotations
 
@@ -36,6 +36,8 @@ from .bss_decode import bss_decode
 from .delta_decode import delta_decode
 from .dict_decode import dict_decode
 from .filter_kernel import filter_range
+from .group_reduce import (CHUNK, LIMB_BITS, MAX_GROUP_SLOTS, group_reduce,
+                           group_slots, pad_groups)
 from .segmented import (plan_segments, seg_bitunpack, seg_delta_decode,
                         seg_dict_decode)
 from .stats_kernel import page_minmax
@@ -43,8 +45,10 @@ from .stats_kernel import page_minmax
 __all__ = ["bitunpack", "bss_decode", "delta_decode", "dict_decode",
            "filter_range", "page_minmax", "decode_on_device",
            "decode_batch_on_device", "range_mask_on_device",
-           "minmax_on_device", "BATCHED", "plan_segments",
-           "seg_bitunpack", "seg_dict_decode", "seg_delta_decode"]
+           "minmax_on_device", "group_sums_on_device", "group_reduce",
+           "group_slots", "pad_groups", "MAX_GROUP_SLOTS", "BATCHED",
+           "plan_segments", "seg_bitunpack", "seg_dict_decode",
+           "seg_delta_decode"]
 
 # encodings with a fused multi-page device decode
 BATCHED = frozenset([enc.BITPACK, enc.DICT, enc.DELTA, enc.BSS])
@@ -109,6 +113,41 @@ def minmax_on_device(values: np.ndarray, *, interpret: bool = False):
     with span("ops.fetch", kernel="page_minmax"):
         return (np.asarray(mins).min().item(),
                 np.asarray(maxs).max().item())
+
+
+def group_sums_on_device(codes: np.ndarray, groups: int, leaves, terms, *,
+                         interpret: bool = False):
+    """``(counts, sums)`` per group through ``group_reduce``: the row count
+    of each of the ``groups`` groups and, for each term, the int64 sum of
+    its values (exact modulo 2**64).  ``codes`` gives each row's group;
+    the caller has proven every leaf exact in int32 and every term's limb
+    count sufficient (``JaxDecodeBackend.group_sums``)."""
+    g = pad_groups(groups)
+    with span("ops.stage", kernel="group_reduce"):
+        n = len(codes)
+        size = max(1, -(-n // CHUNK)) * CHUNK
+        c = np.full(size, -1, np.int32)
+        c[:n] = codes
+        xs = []
+        for v in leaves:
+            x = np.zeros(size, np.int32)
+            x[:n] = v
+            xs.append(x)
+    with span("ops.launch", kernel="group_reduce"):
+        parts = [group_reduce(c[s:s + CHUNK], *(x[s:s + CHUNK] for x in xs),
+                              terms=terms, groups=g, interpret=interpret)
+                 for s in range(0, size, CHUNK)]
+    with span("ops.fetch", kernel="group_reduce"):
+        tot = sum(np.asarray(p).astype(np.int64) for p in parts)[:groups]
+    # the carries: each limb's sum times its weight, wrapping in int64
+    sums, j = [], 1
+    for _, k in terms:
+        s = np.zeros(groups, np.int64)
+        for i in range(k):
+            s += tot[:, j + i] * np.int64(1 << (LIMB_BITS * i))
+        sums.append(s)
+        j += k
+    return tot[:, 0], sums
 
 
 def _pow2_pad(values: np.ndarray) -> np.ndarray:

@@ -5,7 +5,8 @@ chip that is described, not attached, so a kernel the TPU compiler would
 refuse (a block off the tiling, an unsupported primitive) fails here with
 no chip.  Widths: a whole 65,536-value morsel, a 512-value morsel (shorter
 than one lane block), a default 8,192-value page and a default
-131,072-row group (the two-phase reader's range mask).
+131,072-row group (the two-phase reader's range mask, and the grouped
+reduction's chunk at TPC-H Q1's terms).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library, and every test
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import (bitunpack, bss_decode, delta_decode, filter_range,
                            page_minmax)
+from repro.kernels.group_reduce import CHUNK, group_reduce
 from repro.kernels.segmented import (seg_bitunpack, seg_delta_decode,
                                      seg_dict_decode)
 
@@ -116,3 +118,21 @@ def test_filter_range_row_group(one_chip, dtype):
 def test_page_minmax(one_chip, dtype):
     _compile(lambda x: page_minmax(x, 4096),
              jax.ShapeDtypeStruct((MORSEL,), dtype, sharding=one_chip))
+
+
+# TPC-H Q1's five aggregate inputs over (quantity, extendedprice,
+# discount, tax), three limbs each as the decode backend plans them: two
+# columns, discount, and the two computed products
+_DISC_PRICE = ("*", ("x", 1), ("-", ("c", 100), ("x", 2)))
+Q1_TERMS = ((("x", 0), 3), (("x", 1), 3), (_DISC_PRICE, 3),
+            (("*", _DISC_PRICE, ("+", ("c", 100), ("x", 3))), 3),
+            (("x", 2), 3))
+
+
+@pytest.mark.parametrize("groups", [4, 64])
+def test_group_reduce(one_chip, groups):
+    assert CHUNK == ROW_GROUP
+    s = jax.ShapeDtypeStruct((CHUNK,), jnp.int32, sharding=one_chip)
+    terms = Q1_TERMS if groups == 4 else Q1_TERMS[:1]
+    _compile(lambda c, *x: group_reduce(c, *x, terms=terms, groups=groups),
+             s, s, s, s, s)
